@@ -21,6 +21,7 @@ from benchmarks import (bench_algorithms, bench_compression,
                         bench_rs_rr_pf, bench_scheduling, bench_sweep,
                         bench_update_aware)
 from benchmarks import common, roofline
+from repro.core import compat
 
 MODULES = [
     ("scheduling(fig1)", bench_scheduling),
@@ -69,6 +70,7 @@ def main(argv=None) -> None:
                          " never clobber the tracked numbers")
     args = ap.parse_args(argv)
     common.FAST = args.fast
+    compat.use_compile_cache()
     if args.out is None:
         args.out = "BENCH_engine_fast.json" if args.fast else "BENCH_engine.json"
 

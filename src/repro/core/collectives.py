@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.compat import axis_size
-
 PyTree = Any
 
 _POW2 = jnp.asarray([1, 2, 4, 8, 16, 32, 64, 128], dtype=jnp.uint8)
@@ -59,9 +57,18 @@ def _pad_dim0(x: jnp.ndarray, multiple: int) -> Tuple[jnp.ndarray, int]:
 
 def _a2a_chunks(x: jnp.ndarray, axis: str) -> jnp.ndarray:
     """x: (n*c, ...) -> received (n, c, ...) — the reduce-scatter wire phase."""
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     chunks = x.reshape(n, x.shape[0] // n, *x.shape[1:])
     return lax.all_to_all(chunks, axis, split_axis=0, concat_axis=0, tiled=False)
+
+
+def _scale_chunks(full: jnp.ndarray, scales: jnp.ndarray) -> jnp.ndarray:
+    """full: (n*c, ...) gathered chunks; scales: (n,) -> chunk i times
+    scales[i]."""
+    n = scales.shape[0]
+    chunks = full.reshape(n, full.shape[0] // n, *full.shape[1:])
+    return (chunks * scales.reshape(n, *([1] * (chunks.ndim - 1)))
+            ).reshape(full.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -75,7 +82,7 @@ def compressed_allreduce_leaf(
 
     Returns (g_hat identical on all shards of ``axis``, new error state).
     """
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     gf = g.astype(jnp.float32)
     if method == "none" or g.size < min_size:
         if e is not None:
@@ -112,9 +119,7 @@ def compressed_allreduce_leaf(
         q2 = jnp.clip(jnp.round(mean_chunk / scale2), -127, 127).astype(jnp.int8)
         full = lax.all_gather(q2, axis, tiled=True)           # (n*c, ...) s8
         scales2 = lax.all_gather(scale2, axis)                # (n,)
-        c = q2.shape[0]
-        s2view = jnp.repeat(scales2, c).reshape(n * c, *([1] * (full.ndim - 1)))
-        out = (full.astype(jnp.float32) * s2view)[:d0]
+        out = _scale_chunks(full.astype(jnp.float32), scales2)[:d0]
         return out.reshape(g.shape).astype(jnp.float32), e_new
 
     if method == "sign":
@@ -138,10 +143,7 @@ def compressed_allreduce_leaf(
         full_packed = lax.all_gather(packed2, axis, tiled=True)
         scales2 = lax.all_gather(scale2, axis)                # (n,)
         full_signs = unpack_bits(full_packed).astype(jnp.float32) * 2.0 - 1.0
-        c_elems = mean_chunk.shape[0]
-        s2view = jnp.repeat(scales2, c_elems).reshape(
-            n * c_elems, *([1] * (full_signs.ndim - 1)))
-        out = (full_signs * s2view)[:d0]
+        out = _scale_chunks(full_signs, scales2)[:d0]
         return out.reshape(g.shape).astype(jnp.float32), e_new
 
     raise ValueError(f"unknown method {method!r}")

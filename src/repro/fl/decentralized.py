@@ -52,7 +52,7 @@ from repro.core import faults as faults_lib
 from repro.core import topology, wireless
 from repro.core.algorithms import registry as algo_registry
 from repro.core.algorithms.registry import AlgoParams
-from repro.core.compat import shard_map
+from jax import shard_map
 from repro.core.compression import registry as compression
 from repro.core.compression.registry import CompressionParams
 from repro.core.faults import FaultParams

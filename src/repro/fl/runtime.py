@@ -39,8 +39,8 @@ An entire multi-round simulation compiles into **one XLA program**:
   keys the engine cache), so a full multi-policy grid is **one** compiled
   call per (compressor-name, algorithm-name) tuple; ``devices=``/``mesh=``
   shards the flattened variant axis over a 1-D device mesh via
-  ``core.compat.shard_map`` (pow-of-mesh padding + output slicing keeps
-  ragged grids bitwise identical to the vmap path), and ``hcfg=`` /
+  ``jax.shard_map`` (ragged grids are padded to a multiple of the mesh
+  and sliced back), and ``hcfg=`` /
   ``hcfgs=`` route the same grid through the hierarchical engine (the
   backhaul rate is traced, so rate grids share one trace);
 * hierarchical FL (``run_hfl``) is wireless-aware end to end: per-cluster
@@ -679,8 +679,10 @@ def _make_sim_fns(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
         step = make_step(chan, cparams, aparams, fparams, pparams, pol_w,
                          dist, k_rounds, eval_batch)
         ts = jnp.arange(cfg.rounds, dtype=jnp.int32)
+        # under the sharded sweep the carry varies per variant, like key
         (state, *_), outs = lax.scan(
-            step, init_carry(init_params), (ts, batches_all))
+            step, compat.vary_like(init_carry(init_params), key),
+            (ts, batches_all))
         return state.params, outs
 
     # the optional traced axes ride in a fixed relative order — fparams,
@@ -743,6 +745,20 @@ def _mesh_key(mesh) -> Tuple:
             tuple(mesh.axis_names))
 
 
+def _shard_variants(vengine: Callable, mesh, n_var: int) -> Callable:
+    """Shard a vmapped engine's flattened variant axis over the 1-D mesh.
+
+    The ``n_var`` per-variant args split along the mesh axis; the three
+    shared args (initial params, batches, eval batch) replicate. Callers pad
+    the variant count to a multiple of the mesh size first
+    (:func:`_pad_variants`) and slice the outputs back."""
+    from jax.sharding import PartitionSpec as P
+    axis = mesh.axis_names[0]
+    return jax.shard_map(vengine, mesh=mesh,
+                         in_specs=(P(axis),) * n_var + (P(), P(), P()),
+                         out_specs=(P(axis), P(axis)))
+
+
 def _get_engine(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
                 has_eval: bool, *, vmapped: bool = False,
                 policy_axis: Optional[Tuple[str, ...]] = None,
@@ -757,17 +773,7 @@ def _get_engine(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
             in_axes = (0,) * n_var + (None,) * 3
             vengine = jax.vmap(engine, in_axes=in_axes)
             if mesh is not None:
-                # shard the flattened variant axis over the 1-D mesh: the
-                # per-variant args split along it, the shared args (initial
-                # params, batches, eval batch) replicate. Callers pad the
-                # variant count to a multiple of the mesh size first
-                # (_pad_variants) and slice the outputs back.
-                from jax.sharding import PartitionSpec as P
-                axis = mesh.axis_names[0]
-                vengine = compat.shard_map(
-                    vengine, mesh=mesh,
-                    in_specs=(P(axis),) * n_var + (P(), P(), P()),
-                    out_specs=(P(axis), P(axis)))
+                vengine = _shard_variants(vengine, mesh, n_var)
             # broadcast init_params can't alias the per-variant outputs, so
             # there is nothing useful to donate on the sweep path.
             return jax.jit(vengine)
@@ -1831,8 +1837,9 @@ def _make_hfl_fns(cfg: SimConfig, hcfg: HFLConfig,
         step = make_step(chan, cparams, aparams, bh_rate, fparams, pparams,
                          geo, k_rounds, eval_batch)
         ts = jnp.arange(cfg.rounds, dtype=jnp.int32)
-        carry, outs = lax.scan(step, init_carry(init_params),
-                               (ts, batches_all))
+        carry, outs = lax.scan(
+            step, compat.vary_like(init_carry(init_params), key),
+            (ts, batches_all))
         cm = carry[0]
         final = jax.tree.map(
             lambda p0, f: f.astype(p0.dtype), init_params,
@@ -1872,12 +1879,7 @@ def _get_hfl_engine(cfg: SimConfig, hcfg: HFLConfig,
             vengine = jax.vmap(engine,
                                in_axes=(0,) * n_var + (None,) * 3)
             if mesh is not None:
-                from jax.sharding import PartitionSpec as P
-                axis = mesh.axis_names[0]
-                vengine = compat.shard_map(
-                    vengine, mesh=mesh,
-                    in_specs=(P(axis),) * n_var + (P(), P(), P()),
-                    out_specs=(P(axis), P(axis)))
+                vengine = _shard_variants(vengine, mesh, n_var)
             return jax.jit(vengine)
         # no donation: the broadcast to (L, ...) cluster models copies the
         # initial params anyway, so there is no aliasable output buffer

@@ -420,27 +420,35 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
                     else jnp.pad(part, (0, npad - n)).reshape(m, chunk))
         sw_pad = (None if sw is None
                   else jnp.pad(sw, (0, npad - n)).reshape(m, chunk))
-        ef_blocks = _reshape_rows(ef, (m, chunk))
-        ctrl_blocks = _reshape_rows(state.ctrl, (m, chunk))
+        # per-client state stays (npad, ...) in the carry and each block
+        # reads and writes its rows in place: a (m, chunk, ...) view would
+        # cost a relayout copy of the whole state on the TPU's tiled layout
+        def rows(st, b):
+            return jax.tree.map(
+                lambda x: lax.dynamic_slice_in_dim(x, b * chunk, chunk), st)
 
-        def scan_block(_, xs):
-            b, part_b, sw_b, ef_b, ctrl_b = xs
+        def put_rows(st, new, b):
+            return jax.tree.map(
+                lambda x, y: lax.dynamic_update_slice_in_dim(x, y, b * chunk,
+                                                             0), st, new)
+
+        def scan_block(carry, xs):
+            ef_all, ctrl_all = carry
+            b, part_b, sw_b = xs
             ids = chunking.block_ids(b, chunk)
             psums, new_ef_b, new_ctrl_b = client_block(
                 ids, batch_fn(ids) if batch_fn is not None
                 else jax.tree.map(lambda x: x[ids], stacked_batches),
-                part_b, sw_b, ef_b, ctrl_b)
-            return None, (psums, new_ef_b, new_ctrl_b)
+                part_b, sw_b, rows(ef_all, b), rows(ctrl_all, b))
+            return (put_rows(ef_all, new_ef_b, b),
+                    put_rows(ctrl_all, new_ctrl_b, b)), psums
 
-        _, (psums_m, ef_m, ctrl_m) = lax.scan(
-            scan_block, None,
-            (jnp.arange(m, dtype=jnp.int32), part_pad, sw_pad, ef_blocks,
-             ctrl_blocks))
+        (client_error, new_ctrl), psums_m = lax.scan(
+            scan_block, (ef, state.ctrl),
+            (jnp.arange(m, dtype=jnp.int32), part_pad, sw_pad))
         # block partials are aligned subtrees of the full canonical tree, so
         # folding them canonically reproduces the unchunked sum bit-for-bit
         totals = {k: chunking.canonical_sum(v) for k, v in psums_m.items()}
-        client_error = _reshape_rows(ef_m, (npad,), drop=2)
-        new_ctrl = _reshape_rows(ctrl_m, (npad,), drop=2)
     else:
         _check_state_rows(ef, state.ctrl, n, "the client count")
         ids = jnp.arange(n, dtype=jnp.int32)
@@ -549,15 +557,6 @@ def _kernel_sign_ef(flat: jnp.ndarray, e: jnp.ndarray):
     return kernel_ops.sign_ef_rows(flat, e)
 
 
-def _reshape_rows(state_rows, lead: Tuple[int, ...], drop: int = 1):
-    """Reshape the ``drop`` leading axes of per-client state (array,
-    SparseEF, or None) to ``lead`` — (N, ...) <-> (m, c, ...) views."""
-    if state_rows is None:
-        return None
-    return jax.tree.map(lambda x: x.reshape(lead + x.shape[drop:]),
-                        state_rows)
-
-
 def _check_state_rows(ef, ctrl, rows: int, why: str) -> None:
     for name, st in (("client_error", ef), ("ctrl", ctrl)):
         if st is None:
@@ -571,8 +570,12 @@ def _check_state_rows(ef, ctrl, rows: int, why: str) -> None:
 
 
 def _global_norm(tree: PyTree) -> jnp.ndarray:
-    return jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32)))
-                        for x in jax.tree.leaves(tree)))
+    # summed through the canonical tree: a plain reduction's order depends
+    # on what XLA fuses into it, which differs between the chunked and the
+    # unchunked pass
+    return jnp.sqrt(sum(
+        chunking.canonical_sum(jnp.square(x.astype(jnp.float32)).ravel())
+        for x in jax.tree.leaves(tree)))
 
 
 # ---------------------------------------------------------------------------

@@ -30,7 +30,8 @@ import jax.numpy as jnp
 
 from repro.kernels.qsgd import qsgd_pallas, qsgd_rows_pallas
 from repro.kernels.sign_ef import sign_ef_pallas, sign_ef_rows_pallas
-from repro.kernels.topk_mask import block_topk_pallas, topk_rows_pallas
+from repro.kernels.topk_mask import (N_BISECT, block_topk_pallas,
+                                     topk_rows_pallas)
 
 _COLS = 1024
 _ROWS_ALIGN = 8
@@ -104,18 +105,28 @@ def sign_ef_compress(x: jnp.ndarray, e: jnp.ndarray,
 # ---------------------------------------------------------------------------
 # Row-batched APIs: one row = one client message (the chunked client pass)
 # ---------------------------------------------------------------------------
-def _pad_rows(x: jnp.ndarray) -> Tuple[jnp.ndarray, int, int]:
-    """Zero-pad (B, D) to (B', D') with B' % 8 == 0, D' % 128 == 0."""
-    b, d = x.shape
-    bp = (-b) % _ROWS_ALIGN
-    dp = (-d) % 128
-    if bp or dp:
-        x = jnp.pad(x, ((0, bp), (0, dp)))
-    return x, b, d
+# A row block holds at most _BLOCK_COLS columns and _BLOCK_BYTES of
+# lane-padded operand, so every width compiles within the default scoped
+# VMEM; ragged edge blocks are handled by the grid.
+_BLOCK_COLS = 16384
+_BLOCK_BYTES = 512 * 1024
+
+
+def _row_block(x: jnp.ndarray) -> Tuple[int, int]:
+    """The (rows, cols) VMEM block of the row kernels for operand ``x``: a
+    whole dim where it fits the budget, else a multiple of the dtype's
+    (sublane, 128) tile."""
+    rows, cols = x.shape
+    itemsize = x.dtype.itemsize
+    sub = 32 // itemsize
+    bc = cols if cols <= _BLOCK_COLS else _BLOCK_COLS
+    br_cap = max(sub, _BLOCK_BYTES // (max(bc, 128) * itemsize) // sub * sub)
+    return (rows if rows <= br_cap else br_cap), bc
 
 
 def _topk_rows_jnp(x: jnp.ndarray, k: jnp.ndarray) -> jnp.ndarray:
-    """Compiled mirror of the bisection kernel (same math, same N_BISECT)."""
+    """Compiled mirror of the bisection kernel (same math, same N_BISECT,
+    int32 counts)."""
     absx = jnp.abs(x.astype(jnp.float32))
     hi = jnp.max(absx, axis=1, keepdims=True)
     lo = jnp.zeros_like(hi)
@@ -123,12 +134,12 @@ def _topk_rows_jnp(x: jnp.ndarray, k: jnp.ndarray) -> jnp.ndarray:
     def body(_, lohi):
         lo, hi = lohi
         mid = 0.5 * (lo + hi)
-        cnt = jnp.sum((absx >= mid).astype(jnp.float32), axis=1,
+        cnt = jnp.sum((absx >= mid).astype(jnp.int32), axis=1,
                       keepdims=True)
-        take_hi = cnt > k
+        take_hi = cnt.astype(jnp.float32) > k
         return jnp.where(take_hi, mid, lo), jnp.where(take_hi, hi, mid)
 
-    lo, _ = jax.lax.fori_loop(0, 24, body, (lo, hi))
+    lo, _ = jax.lax.fori_loop(0, N_BISECT, body, (lo, hi))
     return jnp.where(absx >= lo, x, jnp.zeros_like(x))
 
 
@@ -141,9 +152,10 @@ def topk_rows(x: jnp.ndarray, k: jnp.ndarray,
     k = jnp.asarray(k, jnp.float32)
     if mode == "jit":
         return _topk_rows_jnp(x, k)
-    xp, b, d = _pad_rows(x)
-    out = topk_rows_pallas(xp, k, interpret=(mode == "interpret"))
-    return out[:b, :d]
+    hi = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=1, keepdims=True)
+    return topk_rows_pallas(x, k, hi,
+                            block=_row_block(x),
+                            interpret=(mode == "interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("mode",))
@@ -161,12 +173,9 @@ def qsgd_rows(x: jnp.ndarray, u: jnp.ndarray, levels: jnp.ndarray,
         lower = jnp.floor(scaled)
         q = (lower + (u < (scaled - lower)).astype(jnp.float32)) / levels
         return (jnp.sign(xf) * q * norms).astype(x.dtype)
-    xp, b, d = _pad_rows(x)
-    up, _, _ = _pad_rows(u)
-    np_ = jnp.pad(norms, ((0, xp.shape[0] - b), (0, 0)))
-    out = qsgd_rows_pallas(xp, up, np_, levels,
-                           interpret=(mode == "interpret"))
-    return out[:b, :d]
+    return qsgd_rows_pallas(x, u, norms, levels,
+                            block=_row_block(x),
+                            interpret=(mode == "interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("mode",))
@@ -175,13 +184,11 @@ def sign_ef_rows(x: jnp.ndarray, e: jnp.ndarray, mode: str | None = None
     """Fused per-row scaled-sign + EF update: c = mean|x+e| * sign(x+e),
     e' = (x+e) - c. x, e: (B, D). Returns (c, e') fp32."""
     mode = resolve_mode(mode)
+    corrected = x.astype(jnp.float32) + e.astype(jnp.float32)
+    scale = jnp.mean(jnp.abs(corrected), axis=1, keepdims=True)
     if mode == "jit":
-        corrected = x.astype(jnp.float32) + e.astype(jnp.float32)
-        scale = jnp.mean(jnp.abs(corrected), axis=1, keepdims=True)
         c = scale * jnp.sign(corrected)
         return c, corrected - c
-    xp, b, d = _pad_rows(x)
-    ep, _, _ = _pad_rows(e.astype(jnp.float32))
-    c, e_new = sign_ef_rows_pallas(xp, ep, jnp.float32(d),
-                                   interpret=(mode == "interpret"))
-    return c[:b, :d], e_new[:b, :d]
+    return sign_ef_rows_pallas(x, e.astype(jnp.float32), scale,
+                               block=_row_block(x),
+                               interpret=(mode == "interpret"))

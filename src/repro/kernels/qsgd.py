@@ -8,10 +8,13 @@ would switch to pltpu.prng_random_bits seeded per-tile (noted in DESIGN.md).
 from __future__ import annotations
 
 import functools
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.core.compat import match_vma
 
 
 def _qsgd_kernel(x_ref, u_ref, norm_ref, o_ref, *, levels: int):
@@ -48,7 +51,8 @@ def qsgd_pallas(x: jnp.ndarray, u: jnp.ndarray, norm: jnp.ndarray,
 
 def _qsgd_rows_kernel(levels_ref, x_ref, u_ref, norms_ref, o_ref):
     """Per-row variant: each row is one client's message with its own L2
-    norm; ``levels`` arrives as a (1, 1) scalar operand so a traced
+    norm (computed outside the kernel, so the block may hold any slice of
+    the row); ``levels`` arrives as a (1, 1) scalar operand so a traced
     CompressionParams.levels sweep shares one compiled kernel."""
     x = x_ref[...].astype(jnp.float32)
     u = u_ref[...]
@@ -58,29 +62,32 @@ def _qsgd_rows_kernel(levels_ref, x_ref, u_ref, norms_ref, o_ref):
     lower = jnp.floor(scaled)
     frac = scaled - lower
     q = (lower + (u < frac).astype(jnp.float32)) / levels
-    o_ref[...] = (jnp.sign(x) * q * norms).astype(o_ref.dtype)
+    # a select, not jnp.sign: sign's TPU lowering fails inside shard_map
+    o_ref[...] = (jnp.where(x < 0, -q, q) * norms).astype(o_ref.dtype)
 
 
 def qsgd_rows_pallas(x: jnp.ndarray, u: jnp.ndarray, norms: jnp.ndarray,
-                     levels: jnp.ndarray, *, block_rows: int = 8,
+                     levels: jnp.ndarray, *, block: Tuple[int, int],
                      interpret: bool = False) -> jnp.ndarray:
     """x, u: (rows, cols); norms: (rows, 1) per-row L2 norms; levels: traced
-    scalar quantization-level count."""
+    scalar quantization-level count; block: the (rows, cols) VMEM block."""
     rows, cols = x.shape
-    assert rows % block_rows == 0 and cols % 128 == 0
     assert norms.shape == (rows, 1), norms.shape
+    br, bc = block
     levels = jnp.asarray(levels, jnp.float32).reshape(1, 1)
-    grid = (rows // block_rows,)
+    levels, x, u, norms = match_vma(levels, x, u, norms)
+    spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
     return pl.pallas_call(
         _qsgd_rows_kernel,
-        grid=grid,
+        grid=(pl.cdiv(rows, br), pl.cdiv(cols, bc)),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            spec,
+            spec,
+            pl.BlockSpec((br, 1), lambda i, j: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       vma=jax.typeof(x).vma),
         interpret=interpret,
     )(levels, x, u, norms)

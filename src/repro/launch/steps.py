@@ -24,7 +24,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import LONG_CONTEXT_WINDOW, ModelConfig, ShapeSpec
 from repro.core.collectives import hierarchical_allreduce
-from repro.core.compat import shard_map
+from jax import shard_map
 from repro.launch.mesh import data_axes, n_data_shards
 from repro.launch import sharding as shard_rules
 from repro.models import transformer as tf

@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.checkpoint import save_checkpoint
 from repro.configs import SHAPES, get_config
+from repro.core import compat
 from repro.core.algorithms import (algo_params, algorithm_names,
                                    from_server_name)
 from repro.core.compression import compression_params, compressor_names
@@ -195,6 +196,7 @@ def main() -> None:
                     help="fixed-point bits per coordinate for the secagg "
                          "finite-field encoding")
     args = ap.parse_args()
+    compat.use_compile_cache()
     if args.cluster:
         run_cluster(args)
     else:

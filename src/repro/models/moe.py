@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core.compat import shard_map
+from jax import shard_map
 from repro.models.layers import dense_init
 
 Params = Dict[str, jnp.ndarray]

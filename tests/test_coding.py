@@ -56,7 +56,8 @@ import jax.numpy as jnp  # noqa: E402
 from repro.core.compression.coding import (field_scale, from_field,  # noqa: E402
                                            to_field)
 
-CLIPS = st.floats(1e-3, 1e3, allow_nan=False, width=32)
+# width=32 bounds must be float32 values themselves (1e-3 is not one)
+CLIPS = st.floats(float(np.float32(1e-3)), 1e3, allow_nan=False, width=32)
 VALS = st.floats(-1e3, 1e3, allow_nan=False, width=32)
 
 

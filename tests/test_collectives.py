@@ -16,9 +16,11 @@ _SCRIPT = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro.core.collectives import (compressed_allreduce_leaf,
                                         hierarchical_allreduce)
-    from repro.core.compat import shard_map
+    from jax import shard_map
 
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                         axis_types=(AxisType.Auto,) * 2)
     n = 8
     # per-shard grads: shared signal + client noise (the FL regime — clients
     # descend the same landscape)

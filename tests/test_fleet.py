@@ -399,6 +399,29 @@ def test_rows_kernels_jit_matches_interpret():
                                rtol=1e-5, atol=1e-6)
 
 
+def test_rows_kernels_tile_wide_ragged_rows():
+    """Rows wider than one column block, with ragged row and column edges:
+    the column-tiled kernels agree with their jnp mirrors."""
+    from repro.kernels import ops, qsgd_rows, sign_ef_rows, topk_rows
+    shape = (3, 2 * ops._BLOCK_COLS + 1000)
+    x = jax.random.normal(jax.random.PRNGKey(5), shape)
+    u = jax.random.uniform(jax.random.PRNGKey(6), shape)
+    e = 0.1 * jax.random.normal(jax.random.PRNGKey(8), shape)
+    k = shape[1] // 100
+
+    np.testing.assert_array_equal(
+        np.asarray(topk_rows(x, k, mode="jit")),
+        np.asarray(topk_rows(x, k, mode="interpret")))
+    np.testing.assert_allclose(
+        np.asarray(qsgd_rows(x, u, 16, mode="jit")),
+        np.asarray(qsgd_rows(x, u, 16, mode="interpret")),
+        rtol=1e-5, atol=1e-6)
+    for got, want in zip(sign_ef_rows(x, e, mode="interpret"),
+                         sign_ef_rows(x, e, mode="jit")):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
+
+
 def test_rows_topk_accepts_traced_k():
     from repro.kernels import topk_rows
     x = jax.random.normal(jax.random.PRNGKey(5), (4, 128))

@@ -66,8 +66,13 @@ print("SHARDED-PARITY-OK")
 
 def _run_forced_8dev(script: str) -> str:
     env = dict(os.environ)
+    # the CPU backend's YNNPACK fusions pick kernels by operand shape, so a
+    # vmap over 18 variants and one over 3 per device would differ in the
+    # last ulp before any sharding happens; with them off, XLA's own CPU
+    # kernels give every variant the same arithmetic at any vmap width
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        " --xla_force_host_platform_device_count=8").strip()
+                        " --xla_force_host_platform_device_count=8"
+                        " --xla_cpu_experimental_ynn_fusion_type=").strip()
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(REPO, "src"), REPO, env.get("PYTHONPATH", "")])
     env["JAX_PLATFORMS"] = "cpu"
