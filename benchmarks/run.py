@@ -1,8 +1,8 @@
 """Benchmark harness: one module per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows (plus the roofline table when
-dry-run artifacts exist) and writes ``BENCH_engine.json`` (name ->
-us_per_call) so the perf trajectory is machine-trackable across PRs.
+Prints ``name,us_per_call,derived`` CSV rows and writes
+``BENCH_engine.json`` (name -> us_per_call) so the perf trajectory is
+machine-trackable across PRs.
 
 Run: ``PYTHONPATH=src python -m benchmarks.run [--fast] [--out PATH]``.
 ``--fast`` caps simulated round counts for smoke use.
@@ -20,7 +20,7 @@ from benchmarks import (bench_algorithms, bench_compression,
                         bench_hfl, bench_kernels, bench_privacy,
                         bench_rs_rr_pf, bench_scheduling, bench_sweep,
                         bench_update_aware)
-from benchmarks import common, roofline
+from benchmarks import common
 from repro.core import compat
 
 MODULES = [
@@ -89,16 +89,8 @@ def main(argv=None) -> None:
     if failures:
         print(f"# {failures} module(s) failed; not writing {args.out} "
               "(partial table would clobber tracked numbers)", file=sys.stderr)
-    else:
-        write_json(args.out)
-
-    try:
-        print("\n=== roofline (from dry-run artifacts) ===")
-        roofline.main()
-    except Exception as e:  # noqa: BLE001
-        print(f"roofline,0,SKIPPED:{e}")
-    if failures:
         raise SystemExit(1)
+    write_json(args.out)
 
 
 if __name__ == "__main__":

@@ -48,6 +48,14 @@ An entire multi-round simulation compiles into **one XLA program**:
   each cluster runs the registry scheduling policy over its members, EF and
   SCAFFOLD ctrl state ride the HFL scan carry, and the periodic SBS->MBS
   sync ships a separately compressed and priced backhaul payload;
+* each stage of a flat round runs under a ``jax.named_scope``
+  (``fl.channel``, ``fl.schedule``, ``fl.data``, ``fl.local_update``,
+  ``fl.compress``, ``fl.client_state``, ``fl.privacy``, ``fl.aggregate``,
+  ``fl.server_update``, ``fl.log``), which names its operations in the
+  compiled program's metadata, and the host side of a call under
+  ``jax.profiler.TraceAnnotation`` spans (``fl.engine_lookup``,
+  ``fl.prepare``, ``fl.dispatch``, ``fl.fetch_logs``), so a profiler
+  trace reads per stage; both are inert when nothing traces;
 * compiled engines are cached per static config (``_ENGINE_CACHE``, bounded
   FIFO) so repeated calls never re-trace; on the single-run path the initial
   params are donated (they alias the returned final params, letting XLA run
@@ -77,6 +85,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from repro.core import chunking, compat, faults as faults_lib
 from repro.core import scheduling, wireless
@@ -456,108 +465,119 @@ def _make_sim_fns(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
                 kd = jax.random.fold_in(kt, DATAGEN_FOLD)
                 batches = functools.partial(cfg.datagen, kd)
 
-            if faults_on:
-                # temporally correlated fading replaces the i.i.d. draw;
-                # round 0 draws the stationary distribution so rho=0
-                # recovers the i.i.d. Rayleigh marginal
-                fad, fading = faults_lib.gauss_markov_fading(
-                    fparams, kt, fad, t)
-            else:
-                fading = wireless.sample_fading_jax(kf, n)
-            snr_lin = wireless.snr_jax(dist, fading, chan)
-            rates = wireless.shannon_rate_jax(
-                snr_lin, chan.bandwidth_hz / cfg.n_scheduled)
-            comp_lat = cfg.comp_latency_s * jax.random.exponential(kc, (n,))
-            if faults_on:
-                # heavy-tailed straggler tail on top of the exponential base
-                comp_lat = comp_lat * faults_lib.straggler_multiplier(
-                    fparams, kt, n)
-            # uplink pricing: the simulated payload is model_bits scaled by
-            # the compressor's bits-per-parameter rate on the actual d-dim
-            # message (data-independent, so the policies can price the round
-            # *before* transmission), times the algorithm's messages-per-
-            # round (SCAFFOLD uplinks delta + ctrl delta -> 2x). "none"
-            # sends exactly model_bits per message.
-            d_model = fl_server.flat_dim(state.params)
-            payload_scale = cfg.model_bits / (32.0 * d_model)
-            if comp_active:
-                bits_dev = message_bits_jax(
-                    cfg.compression, cparams, cfg.model_bits,
-                    d_model) * algo.uplink_factor
-            else:
-                bits_dev = jnp.float32(cfg.model_bits * algo.uplink_factor)
-            mask_over = jnp.float32(0.0)
-            if priv_on:
-                # field modes replace the compressor's rate with dense
-                # field_bits per coordinate (a masked message is
-                # incompressible); the pairwise key agreement adds raw
-                # protocol bits per round — both priced on the uplink
-                if priv.uses_field:
-                    bits_dev = payload_scale * privacy_lib.uplink_bits_jax(
-                        cfg.privacy, pparams, d_model,
-                        0.0) * algo.uplink_factor
-                if priv.uses_masks:
-                    mask_over = privacy_lib.mask_bits_jax(cfg.privacy, n - 1)
-                    bits_dev = bits_dev + mask_over
-            comm_lat = wireless.comm_latency_jax(bits_dev, rates)
-            # per-device time-averaged SNR (PF's denominator), seeded with
-            # the first observation
-            avg_snr = jnp.where(t == 0, snr_lin,
-                                0.9 * avg_snr + 0.1 * snr_lin)
+            with jax.named_scope("fl.channel"):
+                if faults_on:
+                    # temporally correlated fading replaces the i.i.d.
+                    # draw; round 0 draws the stationary distribution so
+                    # rho=0 recovers the i.i.d. Rayleigh marginal
+                    fad, fading = faults_lib.gauss_markov_fading(
+                        fparams, kt, fad, t)
+                else:
+                    fading = wireless.sample_fading_jax(kf, n)
+                snr_lin = wireless.snr_jax(dist, fading, chan)
+                rates = wireless.shannon_rate_jax(
+                    snr_lin, chan.bandwidth_hz / cfg.n_scheduled)
+                comp_lat = cfg.comp_latency_s * jax.random.exponential(
+                    kc, (n,))
+                if faults_on:
+                    # heavy-tailed straggler tail on top of the
+                    # exponential base
+                    comp_lat = comp_lat * faults_lib.straggler_multiplier(
+                        fparams, kt, n)
+                # uplink pricing: the simulated payload is model_bits
+                # scaled by the compressor's bits-per-parameter rate on the
+                # actual d-dim message (data-independent, so the policies
+                # can price the round *before* transmission), times the
+                # algorithm's messages-per-round (SCAFFOLD uplinks delta +
+                # ctrl delta -> 2x). "none" sends exactly model_bits per
+                # message.
+                d_model = fl_server.flat_dim(state.params)
+                payload_scale = cfg.model_bits / (32.0 * d_model)
+                if comp_active:
+                    bits_dev = message_bits_jax(
+                        cfg.compression, cparams, cfg.model_bits,
+                        d_model) * algo.uplink_factor
+                else:
+                    bits_dev = jnp.float32(cfg.model_bits
+                                           * algo.uplink_factor)
+                mask_over = jnp.float32(0.0)
+                if priv_on:
+                    # field modes replace the compressor's rate with dense
+                    # field_bits per coordinate (a masked message is
+                    # incompressible); the pairwise key agreement adds raw
+                    # protocol bits per round — both priced on the uplink
+                    if priv.uses_field:
+                        bits_dev = payload_scale * privacy_lib.uplink_bits_jax(
+                            cfg.privacy, pparams, d_model,
+                            0.0) * algo.uplink_factor
+                    if priv.uses_masks:
+                        mask_over = privacy_lib.mask_bits_jax(cfg.privacy,
+                                                              n - 1)
+                        bits_dev = bits_dev + mask_over
+                comm_lat = wireless.comm_latency_jax(bits_dev, rates)
+                # per-device time-averaged SNR (PF's denominator), seeded
+                # with the first observation
+                avg_snr = jnp.where(t == 0, snr_lin,
+                                    0.9 * avg_snr + 0.1 * snr_lin)
 
-            if faults_on:
-                # Gilbert-Elliott churn: offline devices are invisible to
-                # the policy (score-masked view) and unschedulable
-                avail = faults_lib.churn_step(fparams, kt, avail)
+            with jax.named_scope("fl.schedule"):
+                if faults_on:
+                    # Gilbert-Elliott churn: offline devices are invisible
+                    # to the policy (score-masked view) and unschedulable
+                    avail = faults_lib.churn_step(fparams, kt, avail)
 
-            rstate = scheduling.RoundState(
-                t=t, key=kp, snr_lin=snr_lin, avg_snr=avg_snr, rates=rates,
-                comm_lat=comm_lat, comp_lat=comp_lat, ages=ages,
-                update_norms=norms)
-            rstate_pol = (scheduling.masked_round_state(rstate, avail)
-                          if faults_on else rstate)
-            if policy_fn is not None:
-                mask = policy_fn(pcfg, rstate_pol)
-            else:
-                mask = mixture_fn(pcfg, rstate_pol, pol_w)
-            if faults_on:
-                # index-based policies (random/round_robin) ignore scores,
-                # so offline devices must be intersected out explicitly
-                mask = mask & avail
-            # staleness snapshot *before* this round's resets: a client
-            # aggregated now contributes an update stale by the rounds it
-            # sat out (fault mode tracks true per-client staleness; the
-            # faults-off proxy is the pre-update scheduling age)
-            stal_pre = stal if faults_on else ages
-            ages = scheduling.update_ages_jax(ages, mask)
+                rstate = scheduling.RoundState(
+                    t=t, key=kp, snr_lin=snr_lin, avg_snr=avg_snr,
+                    rates=rates, comm_lat=comm_lat, comp_lat=comp_lat,
+                    ages=ages, update_norms=norms)
+                rstate_pol = (scheduling.masked_round_state(rstate, avail)
+                              if faults_on else rstate)
+                if policy_fn is not None:
+                    mask = policy_fn(pcfg, rstate_pol)
+                else:
+                    mask = mixture_fn(pcfg, rstate_pol, pol_w)
+                if faults_on:
+                    # index-based policies (random/round_robin) ignore
+                    # scores, so offline devices must be intersected out
+                    # explicitly
+                    mask = mask & avail
+                # staleness snapshot *before* this round's resets: a client
+                # aggregated now contributes an update stale by the rounds
+                # it sat out (fault mode tracks true per-client staleness;
+                # the faults-off proxy is the pre-update scheduling age)
+                stal_pre = stal if faults_on else ages
+                ages = scheduling.update_ages_jax(ages, mask)
 
-            if faults_on:
-                # mid-round dropout + SNR-threshold decode failure with up
-                # to max_retries re-priced retransmissions (each re-samples
-                # the channel and re-bills the payload's airtime)
-                dropped = faults_lib.dropout_draw(fparams, kt, n) & mask
-                ok = snr_lin >= fparams.snr_min
-                comm_eff = comm_lat
-                n_retx = jnp.zeros(n, jnp.float32)
-                for r in range(1, cfg.max_retries + 1):
-                    fad_r = faults_lib.retry_fading(kt, r, n)
-                    snr_r = wireless.snr_jax(dist, fad_r, chan)
-                    lat_r = wireless.comm_latency_jax(
-                        bits_dev, wireless.shannon_rate_jax(
-                            snr_r, chan.bandwidth_hz / cfg.n_scheduled))
-                    need = ~ok
-                    comm_eff = comm_eff + jnp.where(need, lat_r, 0.0)
-                    n_retx = n_retx + need.astype(jnp.float32)
-                    ok = ok | (snr_r >= fparams.snr_min)
-                survived = mask & ~dropped & ok
-                part = survived.astype(jnp.float32)
-            else:
-                part = mask.astype(jnp.float32)
+            with jax.named_scope("fl.channel"):
+                if faults_on:
+                    # mid-round dropout + SNR-threshold decode failure with
+                    # up to max_retries re-priced retransmissions (each
+                    # re-samples the channel and re-bills the payload's
+                    # airtime)
+                    dropped = faults_lib.dropout_draw(fparams, kt, n) & mask
+                    ok = snr_lin >= fparams.snr_min
+                    comm_eff = comm_lat
+                    n_retx = jnp.zeros(n, jnp.float32)
+                    for r in range(1, cfg.max_retries + 1):
+                        fad_r = faults_lib.retry_fading(kt, r, n)
+                        snr_r = wireless.snr_jax(dist, fad_r, chan)
+                        lat_r = wireless.comm_latency_jax(
+                            bits_dev, wireless.shannon_rate_jax(
+                                snr_r, chan.bandwidth_hz / cfg.n_scheduled))
+                        need = ~ok
+                        comm_eff = comm_eff + jnp.where(need, lat_r, 0.0)
+                        n_retx = n_retx + need.astype(jnp.float32)
+                        ok = ok | (snr_r >= fparams.snr_min)
+                    survived = mask & ~dropped & ok
+                    part = survived.astype(jnp.float32)
+                else:
+                    part = mask.astype(jnp.float32)
 
             # staleness-aware algorithms (fedbuff) down-weight old updates;
             # everyone else gets None so the baseline trace is unchanged
-            sw = (faults_lib.staleness_weights(aparams, stal_pre)
-                  if algo.uses_staleness else None)
+            with jax.named_scope("fl.aggregate"):
+                sw = (faults_lib.staleness_weights(aparams, stal_pre)
+                      if algo.uses_staleness else None)
             fault_kw = (dict(gate_ef=True, guard_empty=True)
                         if faults_on else {})
             priv_kw = (dict(pparams=pparams, privacy_key=k_priv)
@@ -567,99 +587,108 @@ def _make_sim_fns(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
                     state, batches, aparams=aparams, participation=part,
                     compress_fn=compress_fn, cparams=cparams, key=kz,
                     staleness_weights=sw, **fault_kw, **priv_kw)
-                ubits = payload_scale * metrics["uplink_bits"]
-                if priv_on and priv.uses_masks:
-                    # key-agreement overhead for every *scheduled* client
-                    # (agreement precedes the transmission that may fail)
-                    ubits = ubits + mask_over * jnp.sum(mask)
-                if faults_on:
-                    # bill undecoded attempts' airtime too: retries plus the
-                    # final failed payload of never-decoded clients
-                    ubits = ubits + bits_dev * jnp.sum(jnp.where(
-                        mask & ~dropped,
-                        n_retx + (~ok).astype(jnp.float32), 0.0))
+                with jax.named_scope("fl.compress"):
+                    ubits = payload_scale * metrics["uplink_bits"]
+                    if priv_on and priv.uses_masks:
+                        # key-agreement overhead for every *scheduled*
+                        # client (agreement precedes the transmission that
+                        # may fail)
+                        ubits = ubits + mask_over * jnp.sum(mask)
+                    if faults_on:
+                        # bill undecoded attempts' airtime too: retries
+                        # plus the final failed payload of never-decoded
+                        # clients
+                        ubits = ubits + bits_dev * jnp.sum(jnp.where(
+                            mask & ~dropped,
+                            n_retx + (~ok).astype(jnp.float32), 0.0))
             else:
                 state, metrics = round_fn(
                     state, batches, aparams=aparams, participation=part,
                     staleness_weights=sw, **fault_kw, **priv_kw)
+                with jax.named_scope("fl.compress"):
+                    if faults_on:
+                        ubits = bits_dev * jnp.sum(jnp.where(
+                            mask & ~dropped, 1.0 + n_retx, 0.0))
+                    else:
+                        ubits = bits_dev * jnp.sum(mask)
+
+            with jax.named_scope("fl.channel"):
+                # downlink pricing (always on): the server broadcast of the
+                # global model opens the round — BS power over the full
+                # band, independent fading, slowest scheduled device gates
+                # the sync barrier. With double EF the broadcast is the
+                # compressed server message instead of the raw model.
+                if comp_active and cfg.double_ef:
+                    dl_bits = payload_scale * compression.uplink_bits_jax(
+                        cfg.compression, cparams, d_model)
+                else:
+                    dl_bits = jnp.float32(cfg.model_bits)
+                dl_rate = wireless.shannon_rate_jax(
+                    wireless.downlink_snr_jax(
+                        dist, faults_lib.downlink_fading(kt, n), chan),
+                    chan.bandwidth_hz)
+                dl_lat = wireless.comm_latency_jax(dl_bits, dl_rate)
+                any_sched = jnp.any(mask)
+                dl_s = jnp.max(jnp.where(mask, dl_lat, 0.0))
+                dl_bits_out = jnp.where(any_sched, dl_bits, jnp.float32(0.0))
+
+                # wall-clock: synchronous round = slowest scheduled device;
+                # the comm/comp breakdown is that bottleneck device's
+                # split. A dropped client stops consuming the round (the
+                # server's deadline machinery already excluded it), a
+                # decode-failed one still burns its airtime.
                 if faults_on:
-                    ubits = bits_dev * jnp.sum(jnp.where(
-                        mask & ~dropped, 1.0 + n_retx, 0.0))
+                    comm_c = jnp.where(dropped, 0.0, comm_eff)
+                    comp_c = jnp.where(dropped, 0.0, comp_lat)
                 else:
-                    ubits = bits_dev * jnp.sum(mask)
+                    comm_c, comp_c = comm_lat, comp_lat
+                total = comm_c + comp_c
+                slowest = jnp.argmax(jnp.where(mask, total, -jnp.inf))
+                comm_s = jnp.where(any_sched, comm_c[slowest], 0.0)
+                comp_s = jnp.where(any_sched, comp_c[slowest], 0.0)
+                clock = clock + dl_s + comm_s + comp_s
 
-            # downlink pricing (always on): the server broadcast of the
-            # global model opens the round — BS power over the full band,
-            # independent fading, slowest scheduled device gates the sync
-            # barrier. With double EF the broadcast is the compressed
-            # server message instead of the raw model.
-            if comp_active and cfg.double_ef:
-                dl_bits = payload_scale * compression.uplink_bits_jax(
-                    cfg.compression, cparams, d_model)
-            else:
-                dl_bits = jnp.float32(cfg.model_bits)
-            dl_rate = wireless.shannon_rate_jax(
-                wireless.downlink_snr_jax(
-                    dist, faults_lib.downlink_fading(kt, n), chan),
-                chan.bandwidth_hz)
-            dl_lat = wireless.comm_latency_jax(dl_bits, dl_rate)
-            any_sched = jnp.any(mask)
-            dl_s = jnp.max(jnp.where(mask, dl_lat, 0.0))
-            dl_bits_out = jnp.where(any_sched, dl_bits, jnp.float32(0.0))
-
-            # wall-clock: synchronous round = slowest scheduled device; the
-            # comm/comp breakdown is that bottleneck device's split. A
-            # dropped client stops consuming the round (the server's
-            # deadline machinery already excluded it), a decode-failed one
-            # still burns its airtime.
-            if faults_on:
-                comm_c = jnp.where(dropped, 0.0, comm_eff)
-                comp_c = jnp.where(dropped, 0.0, comp_lat)
-            else:
-                comm_c, comp_c = comm_lat, comp_lat
-            total = comm_c + comp_c
-            slowest = jnp.argmax(jnp.where(mask, total, -jnp.inf))
-            comm_s = jnp.where(any_sched, comm_c[slowest], 0.0)
-            comp_s = jnp.where(any_sched, comp_c[slowest], 0.0)
-            clock = clock + dl_s + comm_s + comp_s
-
-            if faults_on:
-                stal_log = jnp.mean(stal_pre)
-                stal = jnp.where(survived, 0.0, stal + 1.0)
-                retx_log = jnp.sum(jnp.where(mask & ~dropped, n_retx, 0.0))
-                n_surv = jnp.sum(survived).astype(jnp.int32)
-                n_drop = jnp.sum(mask & ~survived).astype(jnp.int32)
-            else:
-                stal_log = jnp.float32(0.0)
-                retx_log = jnp.float32(0.0)
-                n_surv = jnp.sum(mask).astype(jnp.int32)
-                n_drop = jnp.int32(0)
-
-            # --- (epsilon, delta) accounting: one subsampled-Gaussian
-            # round at sampling fraction survivors/N. secagg_dp's local
-            # field noise aggregates to an effective multiplier
-            # sigma * sqrt(survivors); central dp uses sigma directly.
-            if dp_on:
-                n_surv_f = jnp.sum(part)
-                q_frac = n_surv_f / n
-                if priv.dp_local:
-                    z_eff = pparams.sigma * jnp.sqrt(
-                        jnp.maximum(n_surv_f, 1.0))
+            with jax.named_scope("fl.log"):
+                if faults_on:
+                    stal_log = jnp.mean(stal_pre)
+                    stal = jnp.where(survived, 0.0, stal + 1.0)
+                    retx_log = jnp.sum(jnp.where(mask & ~dropped, n_retx,
+                                                 0.0))
+                    n_surv = jnp.sum(survived).astype(jnp.int32)
+                    n_drop = jnp.sum(mask & ~survived).astype(jnp.int32)
                 else:
-                    z_eff = pparams.sigma
-                rdp = rdp + privacy_lib.rdp_increment(q_frac, z_eff)
-                eps = privacy_lib.epsilon_of(rdp)
-                delta_out = jnp.float32(privacy_lib.DELTA)
-            else:
-                eps = jnp.float32(jnp.inf)
-                delta_out = jnp.float32(1.0)
-            mask_bits_out = mask_over * jnp.sum(mask)
+                    stal_log = jnp.float32(0.0)
+                    retx_log = jnp.float32(0.0)
+                    n_surv = jnp.sum(mask).astype(jnp.int32)
+                    n_drop = jnp.int32(0)
 
-            loss = metrics["loss"]
-            if has_eval:
-                loss = loss_fn(state.params, eval_batch)[0]
-            # update-aware policies observe last-round delta norms (proxy)
-            norms = 0.9 * norms + 0.1 * jax.random.exponential(kn, (n,))
+                # --- (epsilon, delta) accounting: one subsampled-Gaussian
+                # round at sampling fraction survivors/N. secagg_dp's local
+                # field noise aggregates to an effective multiplier
+                # sigma * sqrt(survivors); central dp uses sigma directly.
+                if dp_on:
+                    n_surv_f = jnp.sum(part)
+                    q_frac = n_surv_f / n
+                    if priv.dp_local:
+                        z_eff = pparams.sigma * jnp.sqrt(
+                            jnp.maximum(n_surv_f, 1.0))
+                    else:
+                        z_eff = pparams.sigma
+                    rdp = rdp + privacy_lib.rdp_increment(q_frac, z_eff)
+                    eps = privacy_lib.epsilon_of(rdp)
+                    delta_out = jnp.float32(privacy_lib.DELTA)
+                else:
+                    eps = jnp.float32(jnp.inf)
+                    delta_out = jnp.float32(1.0)
+                mask_bits_out = mask_over * jnp.sum(mask)
+
+                loss = metrics["loss"]
+                if has_eval:
+                    loss = loss_fn(state.params, eval_batch)[0]
+            with jax.named_scope("fl.schedule"):
+                # update-aware policies observe last-round delta norms
+                # (proxy)
+                norms = 0.9 * norms + 0.1 * jax.random.exponential(kn, (n,))
             new_carry = (state, clock, ages, norms, avg_snr)
             if faults_on:
                 new_carry = new_carry + (avail, fad, stal)
@@ -675,14 +704,16 @@ def _make_sim_fns(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
               init_params, batches_all, eval_batch):
         ENGINE_STATS["traces"] += 1  # python side effect: runs at trace only
         k_pos, k_rounds = jax.random.split(key)
-        dist = wireless.sample_positions_jax(k_pos, chan, n)
+        with jax.named_scope("fl.channel"):
+            dist = wireless.sample_positions_jax(k_pos, chan, n)
         step = make_step(chan, cparams, aparams, fparams, pparams, pol_w,
                          dist, k_rounds, eval_batch)
         ts = jnp.arange(cfg.rounds, dtype=jnp.int32)
+        with jax.named_scope("fl.client_state"):
+            carry0 = init_carry(init_params)
         # under the sharded sweep the carry varies per variant, like key
         (state, *_), outs = lax.scan(
-            step, compat.vary_like(init_carry(init_params), key),
-            (ts, batches_all))
+            step, compat.vary_like(carry0, key), (ts, batches_all))
         return state.params, outs
 
     # the optional traced axes ride in a fixed relative order — fparams,
@@ -830,25 +861,30 @@ def run_simulation_scan(cfg: SimConfig, loss_fn, init_params: PyTree,
         raise ValueError("run_simulation_scan needs batches= (stack_batches) "
                          "or a SimConfig.datagen")
     wcfg = wcfg or wireless.WirelessConfig(n_devices=cfg.n_devices)
-    engine = _get_engine(cfg, wcfg, loss_fn, eval_batch is not None)
-    key = jax.random.PRNGKey(cfg.seed)
-    chan = wireless.channel_params(wcfg)
-    cparams = _resolve_cparams(cfg, init_params)
-    aparams = _resolve_aparams(cfg)
-    init_copy = jax.tree.map(jnp.array, init_params)  # donated to the engine
-    fargs = (cfg.faults,) if cfg.faults is not None else ()
-    pargs = (_resolve_pparams(cfg),) if cfg.privacy != "none" else ()
-    params, outs = engine(key, chan, cparams, aparams, *fargs, *pargs,
-                          init_copy, batches, eval_batch)
-    (losses, clocks, masks, nsched, ubits, comm_s, comp_s, dl_bits,
-     n_surv, n_drop, retx, stal, eps, dlt, mbits) = jax.device_get(outs)
-    return params, SimLogs(loss=losses, latency_s=clocks,
-                           n_scheduled=nsched, participation=masks,
-                           uplink_bits=ubits, comm_s=comm_s, comp_s=comp_s,
-                           downlink_bits=dl_bits, n_survived=n_surv,
-                           n_dropped=n_drop, retransmissions=retx,
-                           staleness_mean=stal, epsilon=eps, delta=dlt,
-                           mask_bits=mbits)
+    with TraceAnnotation("fl.engine_lookup"):
+        engine = _get_engine(cfg, wcfg, loss_fn, eval_batch is not None)
+    with TraceAnnotation("fl.prepare"):
+        key = jax.random.PRNGKey(cfg.seed)
+        chan = wireless.channel_params(wcfg)
+        cparams = _resolve_cparams(cfg, init_params)
+        aparams = _resolve_aparams(cfg)
+        # donated to the engine
+        init_copy = jax.tree.map(jnp.array, init_params)
+        fargs = (cfg.faults,) if cfg.faults is not None else ()
+        pargs = (_resolve_pparams(cfg),) if cfg.privacy != "none" else ()
+    with TraceAnnotation("fl.dispatch"):
+        params, outs = engine(key, chan, cparams, aparams, *fargs, *pargs,
+                              init_copy, batches, eval_batch)
+    with TraceAnnotation("fl.fetch_logs"):
+        (losses, clocks, masks, nsched, ubits, comm_s, comp_s, dl_bits,
+         n_surv, n_drop, retx, stal, eps, dlt, mbits) = jax.device_get(outs)
+        return params, SimLogs(loss=losses, latency_s=clocks,
+                               n_scheduled=nsched, participation=masks,
+                               uplink_bits=ubits, comm_s=comm_s,
+                               comp_s=comp_s, downlink_bits=dl_bits,
+                               n_survived=n_surv, n_dropped=n_drop,
+                               retransmissions=retx, staleness_mean=stal,
+                               epsilon=eps, delta=dlt, mask_bits=mbits)
 
 
 def run_simulation(cfg: SimConfig, loss_fn, init_params: PyTree,
@@ -1226,21 +1262,25 @@ def run_sweep(cfg: SimConfig, loss_fn, init_params: PyTree, batches: PyTree, *,
                     cfg_v = dataclasses.replace(
                         cfg_variant(policies[0], comp, alg, priv),
                         policy=policies[0])
-                    engine = _get_engine(cfg_v, wcfgs[0], loss_fn, has_eval,
-                                         vmapped=True,
-                                         policy_axis=policy_axis, mesh=mesh)
+                    with TraceAnnotation("fl.engine_lookup"):
+                        engine = _get_engine(cfg_v, wcfgs[0], loss_fn,
+                                             has_eval, vmapped=True,
+                                             policy_axis=policy_axis,
+                                             mesh=mesh)
                     var_args = (base_args
                                 + ((fps_t,) if faults_on else ())
                                 + ((pps_t,) if priv != "none" else ())
                                 + (pol_w,))
-                    outs = _dispatch_variants(engine, var_args, shared,
-                                              mesh)
-                    arrs = jax.device_get(outs)
-                    for p_i, pol in enumerate(policies):
-                        block = tuple(a[p_i * n_base:(p_i + 1) * n_base]
-                                      for a in arrs)
-                        results[result_key(pol, comp, alg,
-                                           priv)] = to_logs(block)
+                    with TraceAnnotation("fl.dispatch"):
+                        outs = _dispatch_variants(engine, var_args, shared,
+                                                  mesh)
+                    with TraceAnnotation("fl.fetch_logs"):
+                        arrs = jax.device_get(outs)
+                        for p_i, pol in enumerate(policies):
+                            block = tuple(a[p_i * n_base:(p_i + 1) * n_base]
+                                          for a in arrs)
+                            results[result_key(pol, comp, alg,
+                                               priv)] = to_logs(block)
         return results
 
     for pol in policies:
@@ -1249,23 +1289,25 @@ def run_sweep(cfg: SimConfig, loss_fn, init_params: PyTree, batches: PyTree, *,
                 for priv in priv_iter:
                     cfg_v = cfg_variant(pol, comp, alg, priv)
                     pargs = (pps,) if priv != "none" else ()
-                    if hlist is not None:
-                        engine = _get_hfl_engine(cfg_v, hlist[0], wcfgs[0],
-                                                 loss_fn, has_eval,
-                                                 vmapped=True, mesh=mesh)
-                        var_args = ((keys, chans, cps, aps, bh)
-                                    + ((fps,) if faults_on else ())
-                                    + pargs)
-                    else:
-                        engine = _get_engine(cfg_v, wcfgs[0], loss_fn,
-                                             has_eval, vmapped=True,
-                                             mesh=mesh)
-                        var_args = ((keys, chans, cps, aps)
-                                    + ((fps,) if faults_on else ())
-                                    + pargs)
-                    outs = _dispatch_variants(engine, var_args, shared,
-                                              mesh)
-                    results[result_key(pol, comp, alg, priv)] = to_logs(outs)
+                    with TraceAnnotation("fl.engine_lookup"):
+                        if hlist is not None:
+                            engine = _get_hfl_engine(
+                                cfg_v, hlist[0], wcfgs[0], loss_fn,
+                                has_eval, vmapped=True, mesh=mesh)
+                        else:
+                            engine = _get_engine(cfg_v, wcfgs[0], loss_fn,
+                                                 has_eval, vmapped=True,
+                                                 mesh=mesh)
+                    var_args = ((keys, chans, cps, aps)
+                                + ((bh,) if hlist is not None else ())
+                                + ((fps,) if faults_on else ())
+                                + pargs)
+                    with TraceAnnotation("fl.dispatch"):
+                        outs = _dispatch_variants(engine, var_args, shared,
+                                                  mesh)
+                    with TraceAnnotation("fl.fetch_logs"):
+                        results[result_key(pol, comp, alg,
+                                           priv)] = to_logs(outs)
     return results
 
 

@@ -262,7 +262,8 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
 
     rows_fn = fused_sign = None
     if comp_active:
-        k_up, k_down, k_ctrl = jax.random.split(key, 3)
+        with jax.named_scope("fl.compress"):
+            k_up, k_down, k_ctrl = jax.random.split(key, 3)
         if compression_name is not None:
             # kernel dispatch keys on the FULL pass size N·D (a static,
             # trace-time fact), never the block size — chunked and unchunked
@@ -274,13 +275,14 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
                               compression_name, n * d))
         else:
             rows_fn = jax.vmap(compress_fn, in_axes=(None, 0, 0))
-    c_tree = (algorithms.unflatten_vec(state.server_opt, state.params)
-              if a.uses_ctrl else None)
-    part = (participation.astype(jnp.float32)
-            if participation is not None else None)
-
-    sw = (staleness_weights.astype(jnp.float32)
-          if staleness_weights is not None else None)
+    with jax.named_scope("fl.local_update"):
+        c_tree = (algorithms.unflatten_vec(state.server_opt, state.params)
+                  if a.uses_ctrl else None)
+    with jax.named_scope("fl.aggregate"):
+        part = (participation.astype(jnp.float32)
+                if participation is not None else None)
+        sw = (staleness_weights.astype(jnp.float32)
+              if staleness_weights is not None else None)
     if gate_ef and part is None:
         raise ValueError("fl_round(gate_ef=True) needs participation= "
                          "(the gate freezes non-participants' EF rows)")
@@ -316,7 +318,8 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
                 f"field sum; legal: {'/'.join(privacy_lib.FIELD_COMPATIBLE)}")
     mask_env = None
     if priv is not None and priv.uses_masks:
-        mask_env = _mask_prepass(privacy_key, n, d, part, chunk_size)
+        with jax.named_scope("fl.privacy"):
+            mask_env = _mask_prepass(privacy_key, n, d, part, chunk_size)
 
     # --- one block of the client pass (Alg. 6/7 lines 4-11) ---------------
     # Per-client work only: local updates, message flattening, EF +
@@ -325,60 +328,68 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
     # gates the sums only (plus, under gate_ef, the EF advancement). The
     # unchunked pass is this function called once.
     def client_block(ids, batches_b, part_b, sw_b, ef_b, ctrl_b):
-        valid = (ids < n).astype(jnp.float32)
-        if a.uses_ctrl:
-            ci_tree = algorithms.unflatten_rows(
-                ctrl_b.astype(jnp.float32), state.params)
+        with jax.named_scope("fl.aggregate"):
+            valid = (ids < n).astype(jnp.float32)
+        with jax.named_scope("fl.local_update"):
+            if a.uses_ctrl:
+                ci_tree = algorithms.unflatten_rows(
+                    ctrl_b.astype(jnp.float32), state.params)
 
-            def one(b, ci):
-                return a.client_update(loss_fn, ap, state.params, b,
-                                       (ci, c_tree))
+                def one(b, ci):
+                    return a.client_update(loss_fn, ap, state.params, b,
+                                           (ci, c_tree))
 
-            deltas, ctrl_deltas, losses = jax.vmap(one)(batches_b, ci_tree)
-            ctrl_flat, _ = flatten_clients(ctrl_deltas)
-        else:
-            def one(b):
-                return a.client_update(loss_fn, ap, state.params, b, None)
-
-            deltas, _, losses = jax.vmap(one)(batches_b)
-            ctrl_flat = None
-        flat, _ = flatten_clients(deltas)            # (c, D) message space
-
-        new_ef_b, ctrl_wire, bits = ef_b, ctrl_flat, None
-        if comp_active:
-            keys_up = chunking.client_keys(k_up, ids)
-            if ef_b is None:
-                flat, bits = rows_fn(cparams, keys_up, flat)
-            elif fused_sign:
-                flat, e_new = _kernel_sign_ef(flat, ef_b.astype(jnp.float32))
-                new_ef_b = e_new.astype(state_dt)
-                bits = jnp.broadcast_to(compression_lib.uplink_bits_jax(
-                    "scaled_sign", cparams, d), (flat.shape[0],))
+                deltas, ctrl_deltas, losses = jax.vmap(one)(batches_b,
+                                                            ci_tree)
             else:
-                e_dense = (error_feedback.densify_rows(ef_b, d) if sparse_ef
-                           else ef_b.astype(jnp.float32))
-                corrected = flat + e_dense
-                flat, bits = rows_fn(cparams, keys_up, corrected)
-                resid = corrected - flat
-                new_ef_b = (error_feedback.sparsify_rows(resid, ef_slots,
-                                                         state_dt)
-                            if sparse_ef else resid.astype(state_dt))
-            if ctrl_flat is not None:
-                # the control-variate delta is a second message on the same
-                # uplink: compressed with the same operator (no EF), billed
-                keys_c = chunking.client_keys(k_ctrl, ids)
-                ctrl_wire, cbits = rows_fn(cparams, keys_c, ctrl_flat)
-                bits = bits + cbits
+                def one(b):
+                    return a.client_update(loss_fn, ap, state.params, b,
+                                           None)
+
+                deltas, _, losses = jax.vmap(one)(batches_b)
+        with jax.named_scope("fl.compress"):
+            ctrl_flat = (flatten_clients(ctrl_deltas)[0] if a.uses_ctrl
+                         else None)
+            flat, _ = flatten_clients(deltas)        # (c, D) message space
+
+            new_ef_b, ctrl_wire, bits = ef_b, ctrl_flat, None
+            if comp_active:
+                keys_up = chunking.client_keys(k_up, ids)
+                if ef_b is None:
+                    flat, bits = rows_fn(cparams, keys_up, flat)
+                elif fused_sign:
+                    flat, e_new = _kernel_sign_ef(flat,
+                                                  ef_b.astype(jnp.float32))
+                    new_ef_b = e_new.astype(state_dt)
+                    bits = jnp.broadcast_to(compression_lib.uplink_bits_jax(
+                        "scaled_sign", cparams, d), (flat.shape[0],))
+                else:
+                    e_dense = (error_feedback.densify_rows(ef_b, d)
+                               if sparse_ef else ef_b.astype(jnp.float32))
+                    corrected = flat + e_dense
+                    flat, bits = rows_fn(cparams, keys_up, corrected)
+                    resid = corrected - flat
+                    new_ef_b = (error_feedback.sparsify_rows(resid, ef_slots,
+                                                             state_dt)
+                                if sparse_ef else resid.astype(state_dt))
+                if ctrl_flat is not None:
+                    # the control-variate delta is a second message on the
+                    # same uplink: compressed with the same operator (no
+                    # EF), billed
+                    keys_c = chunking.client_keys(k_ctrl, ids)
+                    ctrl_wire, cbits = rows_fn(cparams, keys_c, ctrl_flat)
+                    bits = bits + cbits
 
         if gate_ef and comp_active and ef_b is not None:
             # dropped / failed clients' error state carries forward
             # untouched (their residual is not lost against an update that
             # never shipped); a row-select, so surviving rows stay bitwise
-            keep = (part_b != 0)
-            new_ef_b = jax.tree.map(
-                lambda nw, old: jnp.where(
-                    keep.reshape((-1,) + (1,) * (nw.ndim - 1)), nw, old),
-                new_ef_b, ef_b)
+            with jax.named_scope("fl.client_state"):
+                keep = (part_b != 0)
+                new_ef_b = jax.tree.map(
+                    lambda nw, old: jnp.where(
+                        keep.reshape((-1,) + (1,) * (nw.ndim - 1)), nw, old),
+                    new_ef_b, ef_b)
 
         w = valid if part_b is None else part_b
         if priv is not None:
@@ -386,29 +397,34 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
             # field-encode, add local noise; then the cohort's pairwise
             # masks. Masks on non-survivor rows are garbage but harmless —
             # canonical_sum where-selects w == 0 rows away.
-            flat = priv.client_transform(pparams, privacy_key, ids, flat)
-            if mask_env is not None:
-                gsum, cnt = mask_env
-                flat = flat + privacy_lib.pairwise_masks(
-                    privacy_key, ids, d, gsum, cnt)
-            if priv.uses_field and bits is not None:
-                # a masked field message is dense: field_bits per coordinate
-                bits = jnp.broadcast_to(
-                    pparams.field_bits * jnp.float32(d), bits.shape)
-        # staleness discount multiplies the *wire* message in the sum only
-        # (EF above saw the true residual); all-ones weights are bitwise
-        # the unweighted sum (x * 1.0 == x in IEEE-754)
-        dsrc = flat if sw_b is None else flat * sw_b[:, None]
-        psums = {"delta": chunking.canonical_sum(dsrc, w),
-                 "loss": chunking.canonical_sum(losses, valid)}
-        if bits is not None:
-            psums["bits"] = chunking.canonical_sum(bits, w)
+            with jax.named_scope("fl.privacy"):
+                flat = priv.client_transform(pparams, privacy_key, ids, flat)
+                if mask_env is not None:
+                    gsum, cnt = mask_env
+                    flat = flat + privacy_lib.pairwise_masks(
+                        privacy_key, ids, d, gsum, cnt)
+                if priv.uses_field and bits is not None:
+                    # a masked field message is dense: field_bits per
+                    # coordinate
+                    bits = jnp.broadcast_to(
+                        pparams.field_bits * jnp.float32(d), bits.shape)
+        with jax.named_scope("fl.aggregate"):
+            # staleness discount multiplies the *wire* message in the sum
+            # only (EF above saw the true residual); all-ones weights are
+            # bitwise the unweighted sum (x * 1.0 == x in IEEE-754)
+            dsrc = flat if sw_b is None else flat * sw_b[:, None]
+            psums = {"delta": chunking.canonical_sum(dsrc, w),
+                     "loss": chunking.canonical_sum(losses, valid)}
+            if bits is not None:
+                psums["bits"] = chunking.canonical_sum(bits, w)
+            if ctrl_wire is not None:
+                psums["ctrl"] = chunking.canonical_sum(ctrl_wire, w)
         new_ctrl_b = ctrl_b
         if ctrl_wire is not None:
-            psums["ctrl"] = chunking.canonical_sum(ctrl_wire, w)
             # only scheduled clients advance their local control variate
-            new_ctrl_b = (ctrl_b.astype(jnp.float32)
-                          + ctrl_wire * w[:, None]).astype(state_dt)
+            with jax.named_scope("fl.client_state"):
+                new_ctrl_b = (ctrl_b.astype(jnp.float32)
+                              + ctrl_wire * w[:, None]).astype(state_dt)
         return psums, new_ef_b, new_ctrl_b
 
     if chunk_size is not None and chunk_size < n:
@@ -416,17 +432,20 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
         m = chunking.n_blocks(n, chunk)
         npad = m * chunk
         _check_state_rows(ef, state.ctrl, npad, "chunk_size")
-        part_pad = (None if part is None
-                    else jnp.pad(part, (0, npad - n)).reshape(m, chunk))
-        sw_pad = (None if sw is None
-                  else jnp.pad(sw, (0, npad - n)).reshape(m, chunk))
+        with jax.named_scope("fl.aggregate"):
+            part_pad = (None if part is None
+                        else jnp.pad(part, (0, npad - n)).reshape(m, chunk))
+            sw_pad = (None if sw is None
+                      else jnp.pad(sw, (0, npad - n)).reshape(m, chunk))
         # per-client state stays (npad, ...) in the carry and each block
         # reads and writes its rows in place: a (m, chunk, ...) view would
         # cost a relayout copy of the whole state on the TPU's tiled layout
+        @jax.named_scope("fl.client_state")
         def rows(st, b):
             return jax.tree.map(
                 lambda x: lax.dynamic_slice_in_dim(x, b * chunk, chunk), st)
 
+        @jax.named_scope("fl.client_state")
         def put_rows(st, new, b):
             return jax.tree.map(
                 lambda x, y: lax.dynamic_update_slice_in_dim(x, y, b * chunk,
@@ -435,78 +454,98 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
         def scan_block(carry, xs):
             ef_all, ctrl_all = carry
             b, part_b, sw_b = xs
-            ids = chunking.block_ids(b, chunk)
+            with jax.named_scope("fl.data"):
+                ids = chunking.block_ids(b, chunk)
+                batches_b = (batch_fn(ids) if batch_fn is not None
+                             else jax.tree.map(lambda x: x[ids],
+                                               stacked_batches))
             psums, new_ef_b, new_ctrl_b = client_block(
-                ids, batch_fn(ids) if batch_fn is not None
-                else jax.tree.map(lambda x: x[ids], stacked_batches),
-                part_b, sw_b, rows(ef_all, b), rows(ctrl_all, b))
+                ids, batches_b, part_b, sw_b, rows(ef_all, b),
+                rows(ctrl_all, b))
             return (put_rows(ef_all, new_ef_b, b),
                     put_rows(ctrl_all, new_ctrl_b, b)), psums
 
-        (client_error, new_ctrl), psums_m = lax.scan(
-            scan_block, (ef, state.ctrl),
-            (jnp.arange(m, dtype=jnp.int32), part_pad, sw_pad))
+        # the loop stacks the block partials for the fold below: work
+        # under no stage of its own is aggregation's
+        with jax.named_scope("fl.aggregate"):
+            (client_error, new_ctrl), psums_m = lax.scan(
+                scan_block, (ef, state.ctrl),
+                (jnp.arange(m, dtype=jnp.int32), part_pad, sw_pad))
         # block partials are aligned subtrees of the full canonical tree, so
         # folding them canonically reproduces the unchunked sum bit-for-bit
-        totals = {k: chunking.canonical_sum(v) for k, v in psums_m.items()}
+        with jax.named_scope("fl.aggregate"):
+            totals = {k: chunking.canonical_sum(v)
+                      for k, v in psums_m.items()}
     else:
         _check_state_rows(ef, state.ctrl, n, "the client count")
-        ids = jnp.arange(n, dtype=jnp.int32)
-        batches = (batch_fn(ids) if batch_fn is not None else stacked_batches)
+        with jax.named_scope("fl.data"):
+            ids = jnp.arange(n, dtype=jnp.int32)
+            batches = (batch_fn(ids) if batch_fn is not None
+                       else stacked_batches)
         totals, client_error, new_ctrl = client_block(ids, batches, part, sw,
                                                       ef, state.ctrl)
 
     # --- aggregation (Alg. 6 line 12): participation-masked mean ----------
-    nsched = jnp.sum(part) if part is not None else None
-    denom = (jnp.float32(n) if part is None else jnp.maximum(nsched, 1.0))
-    tot_delta = totals["delta"]
+    with jax.named_scope("fl.aggregate"):
+        nsched = jnp.sum(part) if part is not None else None
+        denom = (jnp.float32(n) if part is None
+                 else jnp.maximum(nsched, 1.0))
+        tot_delta = totals["delta"]
     if priv is not None:
         # decode the modular field sum back to float / add central DP noise
         # (noise calibrated to the clipped per-client sensitivity, so it is
         # added to the *sum*, before the mean)
-        tot_delta = priv.server_transform(pparams, privacy_key, tot_delta)
-    mean_delta = algorithms.unflatten_vec(tot_delta / denom, state.params)
-    uplink_bits = totals.get("bits")
+        with jax.named_scope("fl.privacy"):
+            tot_delta = priv.server_transform(pparams, privacy_key,
+                                              tot_delta)
+    with jax.named_scope("fl.aggregate"):
+        mean_delta = algorithms.unflatten_vec(tot_delta / denom,
+                                              state.params)
+        uplink_bits = totals.get("bits")
 
-    # --- downlink (PS-side) EF compression (Alg. 6 lines 15-17) ---
-    server_error = state.server_error
-    if comp_active and server_error is not None:
-        corrected = algorithms.flatten_vec(mean_delta) + server_error
-        c, _ = compress_fn(cparams, k_down, corrected)
-        server_error = corrected - c
-        mean_delta = algorithms.unflatten_vec(c, mean_delta)
+    with jax.named_scope("fl.server_update"):
+        # --- downlink (PS-side) EF compression (Alg. 6 lines 15-17) ---
+        server_error = state.server_error
+        if comp_active and server_error is not None:
+            corrected = algorithms.flatten_vec(mean_delta) + server_error
+            c, _ = compress_fn(cparams, k_down, corrected)
+            server_error = corrected - c
+            mean_delta = algorithms.unflatten_vec(c, mean_delta)
 
-    # --- control-variate bookkeeping (SCAFFOLD) ---
-    # clients advance c_i by the *transmitted* (possibly compressed) ctrl
-    # delta — the same quantity the server integrates into c — so
-    # c = mean(c_i) stays consistent under lossy compression.
-    ctrl_aux = None
-    if a.uses_ctrl:
-        part_frac = (jnp.float32(1.0) if part is None else nsched / n)
-        ctrl_aux = (totals["ctrl"] / denom, part_frac)
+        # --- control-variate bookkeeping (SCAFFOLD) ---
+        # clients advance c_i by the *transmitted* (possibly compressed)
+        # ctrl delta — the same quantity the server integrates into c — so
+        # c = mean(c_i) stays consistent under lossy compression.
+        ctrl_aux = None
+        if a.uses_ctrl:
+            part_frac = (jnp.float32(1.0) if part is None else nsched / n)
+            ctrl_aux = (totals["ctrl"] / denom, part_frac)
 
-    # --- server update (registry triple) ---
-    new_params, new_opt = a.server_update(ap, state.params, mean_delta,
-                                          state.server_opt, ctrl_aux)
+        # --- server update (registry triple) ---
+        new_params, new_opt = a.server_update(ap, state.params, mean_delta,
+                                              state.server_opt, ctrl_aux)
 
-    if guard_empty and part is not None:
-        # graceful degradation: an all-failed round is bitwise a no-op —
-        # the model, server optimizer state, and downlink EF all carry
-        # forward (a zero mean delta is *not* enough: momentum/Adam state
-        # and the fedbuff buffer counter would still advance). Rounds with
-        # any survivor select the freshly computed values elementwise,
-        # which is bitwise the unguarded result.
-        alive = nsched > 0
-        new_params = jax.tree.map(lambda a_, b_: jnp.where(alive, a_, b_),
-                                  new_params, state.params)
-        new_opt = jax.tree.map(lambda a_, b_: jnp.where(alive, a_, b_),
-                               new_opt, state.server_opt)
-        if server_error is not None:
-            server_error = jnp.where(alive, server_error,
-                                     state.server_error)
+        if guard_empty and part is not None:
+            # graceful degradation: an all-failed round is bitwise a no-op
+            # — the model, server optimizer state, and downlink EF all
+            # carry forward (a zero mean delta is *not* enough:
+            # momentum/Adam state and the fedbuff buffer counter would
+            # still advance). Rounds with any survivor select the freshly
+            # computed values elementwise, which is bitwise the unguarded
+            # result.
+            alive = nsched > 0
+            new_params = jax.tree.map(
+                lambda a_, b_: jnp.where(alive, a_, b_), new_params,
+                state.params)
+            new_opt = jax.tree.map(lambda a_, b_: jnp.where(alive, a_, b_),
+                                   new_opt, state.server_opt)
+            if server_error is not None:
+                server_error = jnp.where(alive, server_error,
+                                         state.server_error)
 
-    metrics = {"loss": totals["loss"] / n,
-               "delta_norm": _global_norm(mean_delta)}
+    with jax.named_scope("fl.log"):
+        metrics = {"loss": totals["loss"] / n,
+                   "delta_norm": _global_norm(mean_delta)}
     if uplink_bits is not None:
         metrics["uplink_bits"] = uplink_bits
     return FLState(new_params, client_error, server_error, new_opt,

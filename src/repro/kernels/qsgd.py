@@ -37,6 +37,7 @@ def qsgd_pallas(x: jnp.ndarray, u: jnp.ndarray, norm: jnp.ndarray,
     grid = (rows // block_rows,)
     return pl.pallas_call(
         functools.partial(_qsgd_kernel, levels=levels),
+        name="qsgd_quantize",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
@@ -79,6 +80,7 @@ def qsgd_rows_pallas(x: jnp.ndarray, u: jnp.ndarray, norms: jnp.ndarray,
     spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
     return pl.pallas_call(
         _qsgd_rows_kernel,
+        name="qsgd_rows",
         grid=(pl.cdiv(rows, br), pl.cdiv(cols, bc)),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),

@@ -36,6 +36,7 @@ def sign_ef_pallas(x: jnp.ndarray, e: jnp.ndarray, *, block_rows: int = 8,
     spec = pl.BlockSpec((block_rows, cols), lambda i: (i, 0))
     return pl.pallas_call(
         _sign_ef_kernel,
+        name="sign_ef_compress",
         grid=grid,
         in_specs=[spec, spec],
         out_specs=(spec, spec),
@@ -67,6 +68,7 @@ def sign_ef_rows_pallas(x: jnp.ndarray, e: jnp.ndarray, scale: jnp.ndarray,
     spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
     return pl.pallas_call(
         _sign_ef_rows_kernel,
+        name="sign_ef_rows",
         grid=(pl.cdiv(rows, br), pl.cdiv(cols, bc)),
         in_specs=[pl.BlockSpec((br, 1), lambda i, j: (i, 0)), spec, spec],
         out_specs=(spec, spec),

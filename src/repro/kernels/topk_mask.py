@@ -52,6 +52,7 @@ def block_topk_pallas(x: jnp.ndarray, k: int, *, block_rows: int = 8,
     grid = (rows // block_rows,)
     return pl.pallas_call(
         functools.partial(_topk_kernel, k=k),
+        name="block_topk",
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, cols), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
@@ -120,6 +121,7 @@ def topk_rows_pallas(x: jnp.ndarray, k: jnp.ndarray, hi: jnp.ndarray, *,
     grid = (pl.cdiv(rows, br), N_BISECT + 1, pl.cdiv(cols, bc))
     return pl.pallas_call(
         functools.partial(_topk_rows_kernel, cols=cols),
+        name="topk_rows",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, s, j: (0, 0)),
