@@ -209,7 +209,7 @@ def rows_compressor(name: str, total_elems: int = 0, *,
     ``keys`` must be per-*client* keys (``fold_in(key, client_id)``), so the
     result of row i never depends on which rows share its batch — the
     chunk-invariance contract of the fleet engine. Kernel-backed operators
-    (top-k bisection, QSGD, scaled-sign) dispatch to ``repro.kernels`` when
+    (exact top-k, QSGD, scaled-sign) dispatch to ``repro.kernels`` when
     :func:`kernel_dispatch` fires; ``kernel_mode`` forces the kernel path's
     execution mode ("pallas"/"interpret"/"jit", see kernels.ops) for
     benchmarks and tests.

@@ -30,7 +30,8 @@ import jax.numpy as jnp
 
 from repro.kernels.qsgd import qsgd_pallas, qsgd_rows_pallas
 from repro.kernels.sign_ef import sign_ef_pallas, sign_ef_rows_pallas
-from repro.kernels.topk_mask import (N_BISECT, block_topk_pallas,
+from repro.kernels.topk_mask import (N_PASSES, abs_bits, block_topk_pallas,
+                                     next_prefix, pass_candidates,
                                      topk_rows_pallas)
 
 _COLS = 1024
@@ -124,37 +125,57 @@ def _row_block(x: jnp.ndarray) -> Tuple[int, int]:
     return (rows if rows <= br_cap else br_cap), bc
 
 
+# top-k over a few float32 rows wider than one block streams (rows, lanes)
+# vreg chunks of blocks of _WIDE_BLOCK_BYTES (topk_mask._topk_wide_kernel)
+_WIDE_ROWS = 8
+_WIDE_BLOCK_BYTES = 2 * 2**20
+
+
+def _topk_wide_block(x: jnp.ndarray
+                     ) -> Tuple[Tuple[int, int] | None, int]:
+    """((rows, cols) block, lanes) of the streaming top-k kernel for
+    operand ``x``, or (None, 0) where the row-block kernel takes it. XLA
+    tiles ``rows`` <= 8 float32 rows as (t, 128), t the power of two at or
+    above ``rows``, and a vreg holds 8 / t such tiles: ``lanes`` columns of
+    every row."""
+    rows, cols = x.shape
+    if x.dtype.itemsize != 4 or rows > _WIDE_ROWS:
+        return None, 0
+    lanes = 128 * _WIDE_ROWS // (1 << (rows - 1).bit_length())
+    bc = _WIDE_BLOCK_BYTES // 4096 * lanes  # a vreg is 4096 bytes
+    if cols <= bc:
+        return None, 0
+    return (rows, bc), lanes
+
+
 def _topk_rows_jnp(x: jnp.ndarray, k: jnp.ndarray) -> jnp.ndarray:
-    """Compiled mirror of the bisection kernel (same math, same N_BISECT,
-    int32 counts)."""
-    absx = jnp.abs(x.astype(jnp.float32))
-    hi = jnp.max(absx, axis=1, keepdims=True)
-    lo = jnp.zeros_like(hi)
+    """Compiled mirror of the radix-select kernel (same passes, same
+    candidates, int32 counts)."""
+    bits = abs_bits(x)
 
-    def body(_, lohi):
-        lo, hi = lohi
-        mid = 0.5 * (lo + hi)
-        cnt = jnp.sum((absx >= mid).astype(jnp.int32), axis=1,
-                      keepdims=True)
-        take_hi = cnt.astype(jnp.float32) > k
-        return jnp.where(take_hi, mid, lo), jnp.where(take_hi, hi, mid)
+    def body(p, prefix):
+        counts = [jnp.sum((bits >= cand).astype(jnp.int32), axis=1,
+                          keepdims=True)
+                  for cand in pass_candidates(prefix, p)]
+        return next_prefix(prefix, counts, k, p)
 
-    lo, _ = jax.lax.fori_loop(0, N_BISECT, body, (lo, hi))
-    return jnp.where(absx >= lo, x, jnp.zeros_like(x))
+    prefix = jax.lax.fori_loop(0, N_PASSES, body,
+                               jnp.zeros((x.shape[0], 1), jnp.int32))
+    return jnp.where(bits >= prefix, x, jnp.zeros_like(x))
 
 
 @functools.partial(jax.jit, static_argnames=("mode",))
 def topk_rows(x: jnp.ndarray, k: jnp.ndarray,
               mode: str | None = None) -> jnp.ndarray:
-    """Per-row threshold-bisection top-k. x: (B, D); k: traced scalar keep
+    """Exact per-row top-k: keeps every coordinate at or above the row's
+    k-th largest ``|x|`` (ties included). x: (B, D); k: traced scalar keep
     budget shared by every row. Returns (B, D), x.dtype."""
     mode = resolve_mode(mode)
     k = jnp.asarray(k, jnp.float32)
     if mode == "jit":
         return _topk_rows_jnp(x, k)
-    hi = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=1, keepdims=True)
-    return topk_rows_pallas(x, k, hi,
-                            block=_row_block(x),
+    block, lanes = _topk_wide_block(x)
+    return topk_rows_pallas(x, k, block=block or _row_block(x), lanes=lanes,
                             interpret=(mode == "interpret"))
 
 

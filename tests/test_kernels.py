@@ -92,3 +92,52 @@ def test_qsgd_wrapper_unbiased_statistically():
                                   interpret=True) for i in range(300)])
     np.testing.assert_allclose(np.asarray(qs.mean(0)), np.asarray(x),
                                atol=0.25)
+
+
+# --- exact per-row top-k (topk_rows): radix select against a sort ---
+def _ties(key, shape):
+    """Halves in [-3, 3]: many exact ties and exact zeros per row."""
+    return jnp.round(2 * jax.random.normal(key, shape)) / 2
+
+
+# (x, k): both kernels (row blocks; streamed vreg chunks for a few wide
+# float32 rows), ragged column edges, ties, zeros, bf16, k >= D, fractional k
+ROW_TOPK_CASES = {
+    "wide_ragged": lambda key: (jax.random.normal(key, (3, 2 * 16384 + 1000)),
+                                300),
+    "streamed_ragged": lambda key: (
+        jax.random.normal(key, (3, 2 * 131072 + 1000)), 2000),
+    "streamed_dense": lambda key: (
+        jax.random.normal(key, (2, 128 * 4096 + 128 * 37)), 5291),
+    "narrow_k1": lambda key: (jax.random.normal(key, (4096, 32)), 1),
+    "ties_zeros": lambda key: (_ties(key, (8, 4096)), 700),
+    "ties_zeros_streamed": lambda key: (_ties(key, (2, 300000)), 30000),
+    "bf16": lambda key: (jax.random.normal(key, (4, 5000), jnp.bfloat16), 50),
+    "k_equals_d": lambda key: (jax.random.normal(key, (2, 1000)), 1000),
+    "k_above_d": lambda key: (jax.random.normal(key, (2, 1000)), 5000),
+    "fractional_k": lambda key: (jax.random.normal(key, (4, 128)), 4.5),
+    "fractional_k_streamed": lambda key: (
+        jax.random.normal(key, (2, 300000)), 7.5),
+}
+
+
+def _topk_rows_oracle(x, k):
+    """Keep |x| >= the row's ceil(k)-th largest |x| (every |x| past D)."""
+    x = np.asarray(x)
+    a = np.abs(x.astype(np.float32))
+    kk = int(np.ceil(k))
+    out = np.zeros_like(x)
+    for r in range(x.shape[0]):
+        thr = np.sort(a[r])[::-1][kk - 1] if kk <= x.shape[1] else 0.0
+        out[r] = np.where(a[r] >= thr, x[r], 0)
+    return out
+
+
+@pytest.mark.parametrize("mode", ["interpret", "jit"])
+@pytest.mark.parametrize("case", sorted(ROW_TOPK_CASES))
+def test_topk_rows_is_exact_topk(case, mode):
+    from repro.kernels import topk_rows
+    x, k = ROW_TOPK_CASES[case](jax.random.PRNGKey(11))
+    got = topk_rows(x, jnp.float32(k), mode=mode)  # k traced
+    assert got.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(got), _topk_rows_oracle(x, k))

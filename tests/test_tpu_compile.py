@@ -8,6 +8,7 @@ chip; the description is made inside a fixture, never at import time, so
 every pytest-xdist worker collects the same tests.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +70,28 @@ def test_row_kernel_compiles_for_v5e(kernel, shape, one_chip):
     scalar = _sds((), jnp.float32, one_chip)
     compiled = jax.jit(_KERNELS[kernel]).lower(x, scalar).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_topk_rows_reads_its_operand_in_place(one_chip):
+    """At the GPT-2 cell's block, (2, 124,402,944): one kernel, and it
+    reads the operand as it lies in HBM. No relayout copy of the operand
+    and no XLA pass over it (such as a row maximum) outside the kernel."""
+    d = 124_402_944
+    x = _sds((2, d), jnp.float32, one_chip)
+    scalar = _sds((), jnp.float32, one_chip)
+    text = jax.jit(_KERNELS["topk"]).lower(x, scalar).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    instrs = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+              if re.match(r"\s*(ROOT )?%\S+ = ", ln)]
+    wide = {ln.split(" = ")[0]: ln for ln in instrs
+            if ln.split(" = ")[1].startswith(f"f32[2,{d}]")}
+    kinds = sorted(re.search(r"\} ([\w-]+)\(", ln).group(1)
+                   for ln in wide.values())
+    assert kinds == ["custom-call", "parameter"], wide
+    param = next(n for n, ln in wide.items() if " parameter(" in ln)
+    users = [ln for ln in instrs
+             if re.search(re.escape(param) + r"\b", ln.split(" = ", 1)[1])]
+    assert len(users) == 1 and "tpu_custom_call" in users[0], users
 
 
 @pytest.mark.parametrize("kernel", sorted(_KERNELS))
