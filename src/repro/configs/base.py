@@ -31,6 +31,10 @@ class ModelConfig:
     norm_type: str = "rmsnorm"  # rmsnorm | layernorm
     tie_embeddings: bool = False
     rope_theta: float = 10_000.0
+    # YaRN of the "full" layers of a dense/moe trunk with a block_pattern:
+    # (factor, original_max_position, beta_fast, beta_slow,
+    # attention_factor); empty: plain RoPE in every layer
+    yarn: Tuple[float, ...] = ()
     use_rope: bool = True
     pos_embed: str = "rope"  # rope | learned (whisper decoder)
     max_position: int = 1_048_576  # only used for learned pos-embed tables
@@ -41,11 +45,11 @@ class ModelConfig:
     logit_softcap: float = 0.0  # gemma-style attn-logit soft capping (0 = off)
 
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0          # routed experts: the router's width
+    n_experts_held: int = 0     # experts this chip holds (0: all n_experts)
     n_shared_experts: int = 0
     moe_top_k: int = 0
     d_ff_expert: int = 0
-    capacity_factor: float = 1.25
     router_aux_weight: float = 0.01  # load-balance loss coefficient
 
     # --- SSM (mamba-1) ---
@@ -54,8 +58,9 @@ class ModelConfig:
     expand: int = 2
     dt_rank: int = 0  # 0 -> ceil(d_model/16)
 
-    # --- hybrid (RG-LRU / Griffin) ---
-    block_pattern: Tuple[str, ...] = ()  # e.g. ("rglru", "rglru", "attn")
+    # --- layer periods: hybrid (RG-LRU / Griffin), dense/moe kinds ---
+    block_pattern: Tuple[str, ...] = ()  # e.g. ("rglru", "rglru", "attn"),
+    #                                      ("sliding", "sliding", "full")
     lru_width: int = 0
 
     # --- VLM ---
@@ -83,6 +88,10 @@ class ModelConfig:
         return self.dt_rank if self.dt_rank else max(1, -(-self.d_model // 16))
 
     @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
     def q_per_kv(self) -> int:
         return self.n_heads // max(1, self.n_kv_heads)
 
@@ -106,7 +115,7 @@ class ModelConfig:
 
         def moe_params() -> int:
             p = d * self.n_experts  # router
-            p += self.n_experts * mlp_params(self.d_ff_expert)
+            p += self.experts_held * mlp_params(self.d_ff_expert)
             p += self.n_shared_experts * mlp_params(self.d_ff_expert)
             return p
 
@@ -156,7 +165,8 @@ class ModelConfig:
         return n
 
     def active_param_count(self) -> int:
-        """Active params per token (MoE: shared + top_k routed experts)."""
+        """Active params per token (MoE: shared experts and, of the top_k
+        routed, the share that falls on the experts held)."""
         if self.family != "moe":
             return self.param_count()
         d = self.d_model
@@ -168,7 +178,9 @@ class ModelConfig:
             return d * qd + 2 * d * kvd + qd * d + 2 * d
 
         per_layer = attn_params() + d * self.n_experts
-        per_layer += (self.moe_top_k + self.n_shared_experts) * mult * d * self.d_ff_expert
+        expert = mult * d * self.d_ff_expert
+        per_layer += (self.moe_top_k * self.experts_held * expert
+                      // self.n_experts + self.n_shared_experts * expert)
         n = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         return n + self.n_layers * per_layer
 

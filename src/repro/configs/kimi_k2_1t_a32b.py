@@ -25,5 +25,4 @@ def get_config() -> ModelConfig:
         mlp_type="swiglu",
         norm_type="rmsnorm",
         rope_theta=1_000_000.0,
-        capacity_factor=1.25,
     )

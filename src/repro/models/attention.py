@@ -105,17 +105,19 @@ def attention_core(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
 def self_attention(p: Params, x: jnp.ndarray, *, n_heads: int, n_kv_heads: int,
                    head_dim: int, use_rope: bool, rope_theta: float,
+                   yarn: Tuple[float, ...] = (),
                    window: Optional[int] = None, softcap: float = 0.0,
                    q_chunk: int = 1024,
                    return_kv: bool = False):
-    """Training / prefill self-attention. x: (B,S,d)."""
+    """Training / prefill self-attention. x: (B,S,d). ``yarn``: YaRN
+    parameters of the RoPE (``layers.yarn_frequencies``), or none."""
     b, s, _ = x.shape
     q = project_q(p, x, n_heads, head_dim)
     k, v = project_kv(p, x, n_kv_heads, head_dim)
     if use_rope:
         pos = jnp.arange(s)[None, :]
-        q = apply_rope(q, pos, rope_theta)
-        k = apply_rope(k, pos, rope_theta)
+        q = apply_rope(q, pos, rope_theta, yarn)
+        k = apply_rope(k, pos, rope_theta, yarn)
     out = attention_core(q, k, v, n_kv_heads=n_kv_heads, causal=True,
                          window=window, softcap=softcap, q_chunk=q_chunk)
     out = out.reshape(b, s, n_heads * head_dim) @ p["wo"]
@@ -128,9 +130,12 @@ def decode_self_attention(p: Params, x: jnp.ndarray, cache_k: jnp.ndarray,
                           cache_v: jnp.ndarray, pos: jnp.ndarray, *,
                           n_heads: int, n_kv_heads: int, head_dim: int,
                           use_rope: bool, rope_theta: float,
-                          circular: bool = False, softcap: float = 0.0):
+                          yarn: Tuple[float, ...] = (),
+                          circular: bool = False,
+                          window: Optional[int] = None, softcap: float = 0.0):
     """One decode step. x: (B,1,d); cache_{k,v}: (B,T,K,hd); pos: scalar int32
-    absolute position of the new token.
+    absolute position of the new token. ``window``: keys at most that many
+    positions back are seen (a sliding layer over a cache that is longer).
 
     ``circular=True`` treats the cache as a ring buffer of size T (sliding
     window): keys are stored *with RoPE already applied at their absolute
@@ -143,8 +148,8 @@ def decode_self_attention(p: Params, x: jnp.ndarray, cache_k: jnp.ndarray,
     k_new, v_new = project_kv(p, x, n_kv_heads, head_dim)
     if use_rope:
         pos_arr = jnp.full((b, 1), pos, dtype=jnp.int32)
-        q = apply_rope(q, pos_arr, rope_theta)
-        k_new = apply_rope(k_new, pos_arr, rope_theta)
+        q = apply_rope(q, pos_arr, rope_theta, yarn)
+        k_new = apply_rope(k_new, pos_arr, rope_theta, yarn)
 
     slot = pos % t if circular else jnp.minimum(pos, t - 1)
     cache_k = jax.lax.dynamic_update_slice(cache_k, k_new.astype(cache_k.dtype), (0, slot, 0, 0))
@@ -156,6 +161,10 @@ def decode_self_attention(p: Params, x: jnp.ndarray, cache_k: jnp.ndarray,
         k_valid = jnp.logical_or(pos >= t, slots <= pos)
     else:
         k_valid = slots <= pos
+    if window is not None:
+        # the position each slot holds: the latest one it took
+        k_pos = pos - (pos - slots) % t if circular else slots
+        k_valid &= k_pos > pos - window
 
     g = n_heads // n_kv_heads
     qg = q.reshape(b, 1, n_kv_heads, g, head_dim)

@@ -11,7 +11,8 @@ Conventions
 """
 from __future__ import annotations
 
-from typing import Any, Dict
+import math
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,13 +51,45 @@ def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., seq, heads, head_dim); positions: (..., seq) int32."""
+def yarn_frequencies(head_dim: int, theta: float, yarn: Tuple[float, ...]
+                     ) -> Tuple[jnp.ndarray, float]:
+    """YaRN (Peng et al. 2023, as transformers' ``_compute_yarn_parameters``
+    computes it): (inv_freq (hd/2,), the factor on cos and sin).
+    ``yarn`` = (factor, original_max_position, beta_fast, beta_slow,
+    attention_factor). Dimensions that turn fewer than ``beta_slow`` times
+    over the original context are interpolated (frequency / factor), those
+    that turn more than ``beta_fast`` times keep their frequency, and a
+    linear ramp blends the two between."""
+    factor, original, beta_fast, beta_slow, attention_factor = yarn
+
+    def dim_of(rotations):
+        return (head_dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    freqs = rope_frequencies(head_dim, theta)
+    return freqs / factor * ramp + freqs * (1.0 - ramp), attention_factor
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               yarn: Tuple[float, ...] = ()) -> jnp.ndarray:
+    """x: (..., seq, heads, head_dim); positions: (..., seq) int32. With
+    ``yarn`` (see :func:`yarn_frequencies`) the YaRN frequencies and scale."""
     head_dim = x.shape[-1]
-    freqs = rope_frequencies(head_dim, theta)  # (hd/2,)
+    if yarn:
+        freqs, scale = yarn_frequencies(head_dim, theta, yarn)
+    else:
+        freqs = rope_frequencies(head_dim, theta)  # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., None, :]
+    if yarn:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

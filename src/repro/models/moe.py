@@ -1,13 +1,19 @@
-"""Mixture-of-Experts FFN with capacity-based dispatch (qwen2-moe, kimi-k2).
+"""Mixture-of-experts FFN (qwen2-moe, kimi-k2, mellum2): dropless.
 
-Dispatch is sort-free: positions-in-expert come from a cumsum over one-hot
-assignments; tokens beyond capacity are *dropped* (standard TPU MoE semantics,
-a la GShard/Switch). Expert weight stacks carry a leading expert axis that is
-sharded over the ``model`` mesh axis (expert parallelism); under pjit the
-scatter/gather lowers to the all-to-all-equivalent collectives.
+The router scores all ``n_experts`` and each token takes its ``moe_top_k``
+best (softmax, renormalized over the chosen). A layer's weight stacks hold
+experts ``first_expert ..``; ``routed_experts`` computes their part of the
+output for every token routed to them: the token-expert assignments are
+sorted by expert, each held expert's rows go through grouped products
+(``lax.ragged_dot``), and the rows come back weighted by their gates.
+Nothing is dropped, however unevenly the tokens route.
 
-Experts are padded up to a multiple of the model-axis size (qwen 60 -> 64);
-padded experts receive -inf router logits and are never selected.
+Under expert parallelism (``set_expert_parallel_mesh``) the stacks are
+sharded over the ``model`` axis; each shard runs ``routed_experts`` on its
+slice with its own ``first_expert`` and the partial outputs are summed
+over the axis. A stack that is one chip's share of the experts
+(``n_experts_held`` < ``n_experts``, as in the FL client) runs on its
+device alone.
 """
 from __future__ import annotations
 
@@ -15,48 +21,45 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from jax import shard_map
 from repro.models.layers import dense_init
 
 Params = Dict[str, jnp.ndarray]
 
 
-def _constrain(x: jnp.ndarray, spec: P) -> jnp.ndarray:
-    """Best-effort sharding constraint (no-op without a mesh, e.g. smoke
-    tests). Keeps the dispatch buffers expert-sharded so XLA reshard uses
-    all-to-all instead of full-buffer all-reduces (EXPERIMENTS.md §Perf)."""
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:  # noqa: BLE001 - no mesh / axis not in mesh
-        return x
+_EP_MESH = None  # set by the launch builders; None: one device holds the stacks
 
-
-_EP_MESH = None  # set by launch builders; None -> auto-partitioned path
+# the production mesh's ``model`` axis: stacks of all the experts are padded
+# to a multiple of it so that they shard evenly over it (qwen 60 -> 64)
+EXPERT_PAD_MULTIPLE = 16
 
 
 def set_expert_parallel_mesh(mesh) -> None:
-    """Enable nested-shard_map expert parallelism (launch/steps.py calls this
-    with the production mesh; smoke tests leave it unset)."""
+    """Run the routed experts expert-parallel over ``mesh``'s ``model`` axis
+    (``launch/steps.py`` and ``launch/specs.py`` call this with the
+    production mesh; the FL engine and the smoke tests leave it unset)."""
     global _EP_MESH
     _EP_MESH = mesh if (mesh is not None and "model" in mesh.axis_names) else None
 
 
-def padded_n_experts(cfg: ModelConfig, multiple: int = 16) -> int:
-    e = cfg.n_experts
-    return -(-e // multiple) * multiple
+def expert_stack_size(cfg: ModelConfig) -> int:
+    """Experts in a layer's weight stacks: a chip's share as held; all the
+    experts padded to a multiple of ``EXPERT_PAD_MULTIPLE``, the padding
+    never routed to."""
+    if cfg.experts_held < cfg.n_experts:
+        return cfg.experts_held
+    return -(-cfg.n_experts // EXPERT_PAD_MULTIPLE) * EXPERT_PAD_MULTIPLE
 
 
-def init_moe_block(key, cfg: ModelConfig, dtype, expert_pad_multiple: int = 16) -> Params:
-    d, dff = cfg.d_model, cfg.d_ff_expert
-    e_pad = padded_n_experts(cfg, expert_pad_multiple)
+def init_moe_block(key, cfg: ModelConfig, dtype) -> Params:
+    d, dff, n_stack = cfg.d_model, cfg.d_ff_expert, expert_stack_size(cfg)
     keys = jax.random.split(key, 8)
 
     def stack(k, shape, scale):
-        return (jax.random.normal(k, (e_pad,) + shape) * scale).astype(dtype)
+        return (jax.random.normal(k, (n_stack,) + shape) * scale).astype(dtype)
 
     p = {
         "router": dense_init(keys[0], (d, cfg.n_experts), jnp.float32),
@@ -72,142 +75,165 @@ def init_moe_block(key, cfg: ModelConfig, dtype, expert_pad_multiple: int = 16) 
     return p
 
 
-def moe_forward(p: Params, x: jnp.ndarray, cfg: ModelConfig,
-                expert_pad_multiple: int = 16) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x: (B,S,d) -> (out (B,S,d), aux_loss scalar)."""
-    if _EP_MESH is not None:
-        return moe_forward_ep(p, x, cfg, _EP_MESH, expert_pad_multiple)
-    bsz, s, d = x.shape
-    t = bsz * s
-    e_real, k = cfg.n_experts, cfg.moe_top_k
-    e_pad = padded_n_experts(cfg, expert_pad_multiple)
-    cap = int(max(k, -(-k * t // e_real) * cfg.capacity_factor))
+# ---------------------------------------------------------------------------
+# Grouped products. ``lax.ragged_dot`` batches only where every operand has
+# its batch axis first, and the TPU's ragged dot takes no batch axis at all;
+# a client axis (the engine vmaps its local update) is therefore unrolled
+# into one ragged product per client. The backward pass is written out with
+# the same two products.
+# ---------------------------------------------------------------------------
+_RAGGED_CONTRACTING = lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
-    xf = x.reshape(t, d)
-    logits = (xf.astype(jnp.float32) @ p["router"])  # (T,E_real)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)  # (T,k)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
-    # --- aux load-balance loss (Switch-style) ---
-    me = jnp.mean(probs, axis=0)  # mean router prob per expert
-    assign_onehot = jax.nn.one_hot(top_e, e_real, dtype=jnp.float32)  # (T,k,E)
-    fe = jnp.mean(jnp.sum(assign_onehot, axis=1), axis=0) / k  # fraction per expert
-    aux = e_real * jnp.sum(me * fe)
+def _one_at_a_time(fn):
+    def rule(axis_size, in_batched, *args):
+        outs = [fn(*[a[i] if b else a for a, b in zip(args, in_batched)])
+                for i in range(axis_size)]
+        return jnp.stack(outs), True
+    return rule
 
-    # --- positions within expert (cumsum over flattened (T*k) choices) ---
-    flat_e = top_e.reshape(t * k)
-    onehot = jax.nn.one_hot(flat_e, e_pad, dtype=jnp.int32)  # (T*k, E_pad)
-    pos_all = jnp.cumsum(onehot, axis=0) - 1  # position if assigned
-    flat_pos = jnp.sum(pos_all * onehot, axis=-1)  # (T*k,)
-    overflow = flat_pos >= cap
-    flat_pos = jnp.where(overflow, cap, flat_pos)  # cap slot == dropped (mode=drop)
 
-    # --- dispatch: (E_pad, cap, d) ---
-    xk = jnp.repeat(xf[:, None, :], k, axis=1).reshape(t * k, d)
-    buf = jnp.zeros((e_pad, cap, d), dtype=x.dtype)
-    buf = buf.at[flat_e, flat_pos].add(xk, mode="drop")
-    buf = _constrain(buf, P("model", None, None))
+@jax.custom_batching.custom_vmap
+def _gmm(x, w, sizes):
+    """(rows, d) x (groups, d, f) -> (rows, f): row block g by w[g]."""
+    return lax.ragged_dot(x, w, sizes)
 
-    # --- expert compute (stacked einsum; expert axis sharded over `model`) ---
-    act = jax.nn.silu if cfg.mlp_type == "swiglu" else (
+
+@jax.custom_batching.custom_vmap
+def _tgmm(x, y, sizes):
+    """(rows, d), (rows, f) -> (groups, d, f): x_g^T y_g per row block."""
+    return lax.ragged_dot_general(x, y, sizes, _RAGGED_CONTRACTING)
+
+
+_gmm.def_vmap(_one_at_a_time(_gmm))
+_tgmm.def_vmap(_one_at_a_time(_tgmm))
+
+
+def _in_groups(y, sizes):
+    """``y`` with the rows past the last group zeroed: what the ragged
+    product leaves there is not specified."""
+    rows = jnp.arange(y.shape[0])[:, None]
+    return jnp.where(rows < jnp.sum(sizes), y, jnp.zeros((), y.dtype))
+
+
+@jax.custom_vjp
+def grouped_matmul(x, w, sizes):
+    """Rows of ``x`` in consecutive groups of ``sizes``, group g times
+    ``w[g]``; rows past the last group give zeros."""
+    return _in_groups(_gmm(x, w, sizes), sizes)
+
+
+def _grouped_fwd(x, w, sizes):
+    return _in_groups(_gmm(x, w, sizes), sizes), (x, w, sizes)
+
+
+def _grouped_bwd(res, ct):
+    x, w, sizes = res
+    return (_in_groups(_gmm(ct, jnp.swapaxes(w, 1, 2), sizes), sizes),
+            _tgmm(x, ct, sizes).astype(w.dtype), None)
+
+
+grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def routed_experts(p: Params, xf: jnp.ndarray, cfg: ModelConfig,
+                   first_expert=0) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """xf: (T,d) -> (the held routed experts' part of the output (T,d), the
+    router's load-balance loss). ``p``'s stacks hold experts
+    ``first_expert ..`` of the router's ``n_experts``; ``first_expert`` may
+    be traced."""
+    t, d = xf.shape
+    k, held = cfg.moe_top_k, p["w_gate"].shape[0]
+    with jax.named_scope("model.moe.route"):
+        probs = jax.nn.softmax(xf.astype(jnp.float32) @ p["router"], axis=-1)
+        top_p, top_e = lax.top_k(probs, k)
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        # Switch-style load balance over all experts
+        fe = jnp.mean(jnp.sum(jax.nn.one_hot(top_e, cfg.n_experts), axis=1),
+                      axis=0) / k
+        aux = cfg.n_experts * jnp.sum(jnp.mean(probs, axis=0) * fe)
+        # sort the (token, choice) pairs by held expert, the rest last; a
+        # token picks distinct experts, so at most t * min(k, held) pairs
+        # are held and the first that many rows hold all of them
+        local = top_e.reshape(t * k) - first_expert
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)
+        order = jnp.argsort(key, stable=True)[:t * min(k, held)]
+        sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        token = order // k
+        xs = xf[token]
+    with jax.named_scope("model.moe.experts"):
+        act = _activation(cfg)
+        h = (act(grouped_matmul(xs, p["w_gate"], sizes))
+             * grouped_matmul(xs, p["w_up"], sizes))
+        ys = grouped_matmul(h, p["w_down"], sizes)
+    with jax.named_scope("model.moe.combine"):
+        gate = jnp.where(mine, top_p.reshape(t * k), 0.0)[order]
+        out = jnp.zeros_like(xf).at[token].add(ys * gate[:, None].astype(
+            ys.dtype))
+    return out, aux
+
+
+def _activation(cfg: ModelConfig):
+    return jax.nn.silu if cfg.mlp_type == "swiglu" else (
         lambda v: jax.nn.gelu(v, approximate=True))
-    h = act(jnp.einsum("ecd,edf->ecf", buf, p["w_gate"]))
-    h = h * jnp.einsum("ecd,edf->ecf", buf, p["w_up"])
-    h = _constrain(h, P("model", None, None))
-    out_buf = jnp.einsum("ecf,efd->ecd", h, p["w_down"])  # (E_pad, cap, d)
-    out_buf = _constrain(out_buf, P("model", None, None))
-
-    # --- combine: gather back, weight, drop overflows ---
-    gathered = out_buf.at[flat_e, flat_pos].get(mode="fill", fill_value=0)  # (T*k, d)
-    w = (top_p.reshape(t * k) * (~overflow)).astype(x.dtype)
-    out = jnp.sum((gathered * w[:, None]).reshape(t, k, d), axis=1)
-
-    if cfg.n_shared_experts:
-        hs = act(xf @ p["shared_gate"]) * (xf @ p["shared_up"])
-        out = out + hs @ p["shared_down"]
-    return out.reshape(bsz, s, d), aux
 
 
 # ---------------------------------------------------------------------------
-# Expert-parallel MoE via nested shard_map over the model axis.
-#
-# The auto-partitioned scatter/gather dispatch above lets XLA all-reduce the
-# full (T*k, d) cotangent buffer over the model axis in fp32 every layer
-# (measured 36.8 s collective term on kimi-k2 x train_4k — EXPERIMENTS.md
-# §Perf). Here dispatch/combine are shard-LOCAL: tokens are replicated across
-# the model axis already (post attention all-reduce), each shard routes them
-# to its own expert slice, and only the combined (T, d) bf16 partial output
-# crosses the wire as a psum.
+# Expert parallelism: a shard_map over the ``model`` axis. Tokens are
+# replicated across it already (after the attention's all-reduce); each
+# shard routes them to the slice of the expert stacks it holds, and only
+# the (T, d) partial outputs cross the wire, as one psum. Letting XLA
+# partition the dispatch instead all-reduces the (T*k, d) cotangents in
+# float32 every layer (a measured 36.8 s collective term on kimi-k2 x
+# train_4k).
 # ---------------------------------------------------------------------------
-def moe_forward_ep(p: Params, x: jnp.ndarray, cfg: ModelConfig, mesh,
-                   expert_pad_multiple: int = 16,
-                   axis: str = "model") -> Tuple[jnp.ndarray, jnp.ndarray]:
-    bsz, s, d = x.shape
-    t = bsz * s
-    e_real, k = cfg.n_experts, cfg.moe_top_k
-    e_pad = padded_n_experts(cfg, expert_pad_multiple)
-    cap = int(max(k, -(-k * t // e_real) * cfg.capacity_factor))
+def _expert_parallel(p: Params, xf: jnp.ndarray, cfg: ModelConfig, mesh,
+                     axis: str = "model") -> Tuple[jnp.ndarray, jnp.ndarray]:
+    # inside an outer shard_map the context mesh (with its manual axes)
+    # must be used; under plain jit the concrete mesh
+    ctx = jax.sharding.get_abstract_mesh()
+    use_mesh = ctx if axis in ctx.axis_names else mesh
+    per_shard = p["w_gate"].shape[0] // use_mesh.shape[axis]
 
-    xf = x.reshape(t, d)
-    logits = xf.astype(jnp.float32) @ p["router"]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    def local(xf_, shard, router, wg, wu, wd):
+        # ``shard``: this shard's index along ``axis``, delivered as a
+        # sharded iota (lax.axis_index lowers to a partition-id computation
+        # that re-binds the outer manual axes, which the sdy verifier
+        # rejects)
+        out, aux = routed_experts(
+            {"router": router, "w_gate": wg, "w_up": wu, "w_down": wd}, xf_,
+            cfg, first_expert=shard[0] * per_shard)
+        return lax.psum(out, axis), aux
 
-    me = jnp.mean(probs, axis=0)
-    assign_onehot = jax.nn.one_hot(top_e, e_real, dtype=jnp.float32)
-    fe = jnp.mean(jnp.sum(assign_onehot, axis=1), axis=0) / k
-    aux = e_real * jnp.sum(me * fe)
-
-    flat_e = top_e.reshape(t * k)
-    onehot = jax.nn.one_hot(flat_e, e_pad, dtype=jnp.int32)
-    pos_all = jnp.cumsum(onehot, axis=0) - 1
-    flat_pos = jnp.sum(pos_all * onehot, axis=-1)
-    overflow = flat_pos >= cap
-    weights = (top_p.reshape(t * k) * (~overflow)).astype(x.dtype)
-
-    def local_block(xf_, flat_e_, flat_pos_, weights_, my_id, wg, wu, wd):
-        # my_id: (1,) this shard's model-axis index, delivered as a sharded
-        # iota input (lax.axis_index lowers to a partition-id computation
-        # that re-binds the outer manual axes — sdy verifier rejects it)
-        e_local = wg.shape[0]
-        lo = my_id[0] * e_local
-        le = flat_e_ - lo
-        mine = (le >= 0) & (le < e_local) & (flat_pos_ < cap)
-        le = jnp.clip(le, 0, e_local - 1)
-        pos = jnp.where(mine, flat_pos_, cap)  # cap slot == dropped
-        xk = jnp.repeat(xf_[:, None, :], k, axis=1).reshape(t * k, d)
-        buf = jnp.zeros((e_local, cap, d), dtype=xf_.dtype)
-        buf = buf.at[le, pos].add(xk, mode="drop")
-        act = jax.nn.silu if cfg.mlp_type == "swiglu" else (
-            lambda v: jax.nn.gelu(v, approximate=True))
-        h = act(jnp.einsum("ecd,edf->ecf", buf, wg))
-        h = h * jnp.einsum("ecd,edf->ecf", buf, wu)
-        out_buf = jnp.einsum("ecf,efd->ecd", h, wd)
-        gathered = out_buf.at[le, pos].get(mode="fill", fill_value=0)
-        gathered = gathered * (weights_ * mine).astype(gathered.dtype)[:, None]
-        contrib = jnp.sum(gathered.reshape(t, k, d), axis=1)
-        return jax.lax.psum(contrib, axis)
-
-    # inside an outer shard_map the context mesh (with its Manual axis types)
-    # must be used; under plain jit fall back to the concrete mesh
-    try:
-        ctx = jax.sharding.get_abstract_mesh()
-        use_mesh = ctx if (ctx is not None and axis in ctx.axis_names) else mesh
-    except Exception:  # noqa: BLE001
-        use_mesh = mesh
-    shard_ids = jnp.arange(use_mesh.shape[axis], dtype=jnp.int32)
-    out = shard_map(
-        local_block, mesh=use_mesh,
-        in_specs=(P(), P(), P(), P(), P(axis), P(axis), P(axis), P(axis)),
-        out_specs=P(), axis_names={axis}, check_vma=False,
-    )(xf, flat_e, flat_pos, weights, shard_ids,
+    return shard_map(
+        local, mesh=use_mesh,
+        in_specs=(P(), P(axis), P(), P(axis), P(axis), P(axis)),
+        out_specs=(P(), P()), axis_names={axis}, check_vma=False,
+    )(xf, jnp.arange(use_mesh.shape[axis], dtype=jnp.int32), p["router"],
       p["w_gate"], p["w_up"], p["w_down"])
 
+
+def moe_forward(p: Params, x: jnp.ndarray, cfg: ModelConfig
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """x: (B,S,d) -> (out (B,S,d), the router's load-balance loss): the
+    routed experts of ``p``'s stacks, over the ``model`` axis where expert
+    parallelism is set and the stacks divide it, plus the shared
+    experts."""
+    bsz, s, d = x.shape
+    xf = x.reshape(bsz * s, d)
+    mesh = _EP_MESH
+    if mesh is not None and p["w_gate"].shape[0] % mesh.shape["model"] == 0:
+        out, aux = _expert_parallel(p, xf, cfg, mesh)
+    else:
+        out, aux = routed_experts(p, xf, cfg)
     if cfg.n_shared_experts:
-        act = jax.nn.silu if cfg.mlp_type == "swiglu" else (
-            lambda v: jax.nn.gelu(v, approximate=True))
-        hs = act(xf @ p["shared_gate"]) * (xf @ p["shared_up"])
-        out = out + hs @ p["shared_down"]
+        with jax.named_scope("model.moe.experts"):
+            act = _activation(cfg)
+            hs = act(xf @ p["shared_gate"]) * (xf @ p["shared_up"])
+            shared = hs @ p["shared_down"]
+        with jax.named_scope("model.moe.combine"):
+            out = out + shared
     return out.reshape(bsz, s, d), aux

@@ -6,8 +6,11 @@ Families: dense | moe | ssm (mamba) | hybrid (RG-LRU+local attn) | vlm
 Design rules (see DESIGN.md):
 * params are dict pytrees with a leading stacked-layer axis; ``lax.scan`` runs
   the stack (compile time stays bounded at 126 layers).
-* hybrid/vlm use *superblocks* (one block-pattern period) so the scanned unit
-  stays homogeneous.
+* hybrid/vlm use *superblocks* (one block-pattern period) so the scanned
+  unit stays homogeneous. A dense/moe trunk with a ``block_pattern`` keeps
+  its flat layer stack and scans it a period at a time: its layers are
+  "sliding" (windowed, plain RoPE) or "full" (whole context, YaRN RoPE
+  where ``cfg.yarn`` is given).
 * training loss is computed with a sequence-chunked, rematerialized
   softmax-xent so full (B,S,V) logits are never materialized.
 """
@@ -169,14 +172,18 @@ def init_params(cfg: ModelConfig, key) -> Params:
 # ===========================================================================
 # Block applications (single layer)
 # ===========================================================================
-def _attn_block_fwd(p: Params, x, cfg: ModelConfig, *, window, return_kv=False,
-                    q_chunk=1024, causal=True):
+def _attn_block_fwd(p: Params, x, cfg: ModelConfig, *, window, yarn=(),
+                    return_kv=False, q_chunk=1024, causal=True):
     h = apply_norm(p["norm1"], x, cfg.norm_type)
-    res = attn.self_attention(
-        p["attn"], h, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-        head_dim=cfg.head_dim, use_rope=cfg.use_rope, rope_theta=cfg.rope_theta,
-        window=window, softcap=cfg.logit_softcap, q_chunk=q_chunk,
-        return_kv=return_kv) if causal else _bidir_attn(p, h, cfg, q_chunk)
+    with jax.named_scope("model.attn.sliding" if window else
+                         "model.attn.full"):
+        res = attn.self_attention(
+            p["attn"], h, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+            head_dim=cfg.head_dim, use_rope=cfg.use_rope,
+            rope_theta=cfg.rope_theta, yarn=yarn, window=window,
+            softcap=cfg.logit_softcap, q_chunk=q_chunk,
+            return_kv=return_kv) if causal else _bidir_attn(p, h, cfg,
+                                                             q_chunk)
     if return_kv:
         res, kv = res
     x = x + res
@@ -201,12 +208,13 @@ def _bidir_attn(p, h, cfg: ModelConfig, q_chunk):
     return out.reshape(b, s, cfg.n_heads * cfg.head_dim) @ p["attn"]["wo"]
 
 
-def _attn_block_decode(p: Params, x, ck, cv, pos, cfg: ModelConfig, *, circular):
+def _attn_block_decode(p: Params, x, ck, cv, pos, cfg: ModelConfig, *, circular,
+                       window=None, yarn=()):
     h = apply_norm(p["norm1"], x, cfg.norm_type)
     res, (ck, cv) = attn.decode_self_attention(
         p["attn"], h, ck, cv, pos, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
         head_dim=cfg.head_dim, use_rope=cfg.use_rope, rope_theta=cfg.rope_theta,
-        circular=circular, softcap=cfg.logit_softcap)
+        yarn=yarn, circular=circular, window=window, softcap=cfg.logit_softcap)
     x = x + res
     h2 = apply_norm(p["norm2"], x, cfg.norm_type)
     if cfg.family == "moe" and "router" in p["mlp"]:
@@ -298,6 +306,50 @@ def unembed(params: Params, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
 
 
 # ===========================================================================
+# Layer periods of a dense / moe trunk
+# ===========================================================================
+def _layer_kinds(cfg: ModelConfig, window):
+    """(window, yarn) of each layer of one period of a dense/moe trunk: one
+    layer of ``window`` and plain RoPE, or the layers of
+    ``cfg.block_pattern``: "sliding" (``cfg.sliding_window``, plain RoPE)
+    and "full" (the whole context, ``cfg.yarn``)."""
+    if not cfg.block_pattern:
+        return ((window, ()),)
+    if cfg.n_layers % len(cfg.block_pattern):
+        raise ValueError(f"{cfg.name}: {cfg.n_layers} layers are not whole "
+                         f"periods of {cfg.block_pattern}")
+    kinds = {"sliding": (cfg.sliding_window, ()), "full": (None, cfg.yarn)}
+    return tuple(kinds[k] for k in cfg.block_pattern)
+
+
+def _periods(tree, period: int):
+    """Layer stacks (L, ...) as (L / period, period, ...)."""
+    if period == 1:
+        return tree
+    return jax.tree.map(lambda a: a.reshape(-1, period, *a.shape[1:]), tree)
+
+
+def _flat_layers(tree, period: int):
+    """The inverse of :func:`_periods`."""
+    if period == 1:
+        return tree
+    return jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), tree)
+
+
+def _layer_of(tree, i: int, period: int):
+    """Layer ``i`` of one period's slice of :func:`_periods`."""
+    return tree if period == 1 else jax.tree.map(lambda a: a[i], tree)
+
+
+def _stack_period(per_layer):
+    """Per-layer outputs of one period, stacked as :func:`_layer_of` reads
+    them."""
+    if len(per_layer) == 1:
+        return per_layer[0]
+    return jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
+
+
+# ===========================================================================
 # Forward (train / prefill trunk)
 # ===========================================================================
 def forward_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -313,17 +365,30 @@ def forward_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     cache = None
 
     if fam in ("dense", "moe"):
-        def body(carry, p_l):
+        kinds = _layer_kinds(cfg, window)
+        period = len(kinds)
+        remat_each = remat and not collect_cache
+
+        def layer(p_l, x, w, yarn):
+            return _attn_block_fwd(p_l, x, cfg, window=w, yarn=yarn,
+                                   return_kv=collect_cache, q_chunk=q_chunk)
+        if remat_each and period > 1:
+            # each layer of a period rematerialized on its own
+            layer = jax.checkpoint(layer, static_argnums=(2, 3))
+
+        def body(carry, p_per):
             x, aux = carry
-            if collect_cache:
-                x, a, kv = _attn_block_fwd(p_l, x, cfg, window=window,
-                                           return_kv=True, q_chunk=q_chunk)
-                return (x, aux + a), kv
-            x, a = _attn_block_fwd(p_l, x, cfg, window=window, q_chunk=q_chunk)
-            return (x, aux + a), None
-        body_fn = jax.checkpoint(body) if (remat and not collect_cache) else body
-        (x, aux), kvs = jax.lax.scan(body_fn, (x, aux0), params["blocks"])
+            kvs = []
+            for i, (w, yarn) in enumerate(kinds):
+                x, a, *kv = layer(_layer_of(p_per, i, period), x, w, yarn)
+                aux = aux + a
+                kvs += kv
+            return (x, aux), (_stack_period(kvs) if collect_cache else None)
+        body_fn = jax.checkpoint(body) if remat_each and period == 1 else body
+        (x, aux), kvs = jax.lax.scan(body_fn, (x, aux0),
+                                     _periods(params["blocks"], period))
         if collect_cache:
+            kvs = _flat_layers(kvs, period)
             cache = {"k": kvs[0], "v": kvs[1]}  # (L,B,S,K,hd)
         return x, aux, cache
 
@@ -545,11 +610,22 @@ def decode_step(params: Params, cfg: ModelConfig, cache: PyTree,
     circ = circular or cfg.attn_type == "sliding"
 
     if fam in ("dense", "moe"):
+        kinds = _layer_kinds(cfg, None)
+        period = len(kinds)
+
         def body(x, inp):
-            p_l, ck, cv = inp
-            x, ck, cv = _attn_block_decode(p_l, x, ck, cv, pos, cfg, circular=circ)
-            return x, (ck, cv)
-        x, (ks, vs) = jax.lax.scan(body, x, (params["blocks"], cache["k"], cache["v"]))
+            p_per, c_per = inp
+            out = []
+            for i, (w, yarn) in enumerate(kinds):
+                ck, cv = _layer_of(c_per, i, period)
+                x, ck, cv = _attn_block_decode(
+                    _layer_of(p_per, i, period), x, ck, cv, pos, cfg,
+                    circular=circ, window=w, yarn=yarn)
+                out.append((ck, cv))
+            return x, _stack_period(out)
+        x, kvs = jax.lax.scan(body, x, _periods(
+            (params["blocks"], (cache["k"], cache["v"])), period))
+        ks, vs = _flat_layers(kvs, period)
         cache = {"k": ks, "v": vs}
 
     elif fam == "ssm":

@@ -76,11 +76,9 @@ def test_decode_step_shapes_and_finiteness(arch):
 
 @pytest.mark.slow
 @pytest.mark.parametrize("arch", ["gemma-2b", "falcon-mamba-7b",
-                                  "recurrentgemma-2b", "stablelm-12b"])
+                                  "recurrentgemma-2b", "stablelm-12b",
+                                  "qwen2-moe-a2.7b"])
 def test_prefill_decode_consistency(arch):
-    # NOTE: MoE archs are excluded — capacity-based dispatch drops different
-    # tokens for different sequence lengths (GShard semantics), so prefill
-    # and teacher-forced logits are not bit-comparable.
     """Teacher-forced forward logits at position t == decode-step logits after
     prefilling t tokens (the serving path computes the same function)."""
     cfg = get_config(arch).reduced()
