@@ -18,7 +18,7 @@ the engine's chunked/unchunked bitwise parity still holds within the mode.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, NamedTuple, Tuple
+from typing import Any, Callable, NamedTuple, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -32,13 +32,15 @@ class SparseEF(NamedTuple):
     indices: jnp.ndarray   # (N, S) int32 coordinates into the D-dim message
 
 
-def init_sparse_error(n: int, d: int, slots: int,
+def init_sparse_error(n: Union[int, Tuple[int, ...]], d: int, slots: int,
                       dtype=jnp.float32) -> SparseEF:
+    """``n`` rows, or a row shape such as the chunked pass's (m, chunk)."""
     if not 1 <= slots <= d:
         raise ValueError(f"sparse EF needs 1 <= slots <= d, got "
                          f"slots={slots}, d={d}")
-    return SparseEF(jnp.zeros((n, slots), dtype),
-                    jnp.zeros((n, slots), jnp.int32))
+    rows = n if isinstance(n, tuple) else (n,)
+    return SparseEF(jnp.zeros((*rows, slots), dtype),
+                    jnp.zeros((*rows, slots), jnp.int32))
 
 
 def densify_rows(ef: SparseEF, d: int) -> jnp.ndarray:

@@ -17,8 +17,9 @@ An entire multi-round simulation compiles into **one XLA program**:
   *name* is static while every hyperparameter (lr, momentum, prox_mu,
   server_lr, ...) rides the traced :class:`AlgoParams` — so a learning-rate
   grid vmaps instead of retracing. SCAFFOLD's per-client control variates
-  are a flat (N, D) matrix in the scan carry and its second uplink message
-  doubles the priced bits-on-the-wire;
+  are an (N, D) matrix in the scan carry ((m, chunk, D) blocks when the
+  client pass is chunked) and its second uplink message doubles the
+  priced bits-on-the-wire;
 * ``run_simulation_scan`` wraps one round as a ``lax.scan`` body whose carry
   is ``(FLState, wall_clock, ages, update_norms, avg_snr)`` — the last being
   the per-device time-averaged-SNR EMA behind true proportional-fair;
@@ -394,11 +395,12 @@ def _make_sim_fns(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
     compress_fn = (compression.get_compressor(cfg.compression)
                    if comp_active else None)
     # chunk >= N degenerates to the unchunked pass (and would otherwise
-    # change the canonical padding); per-client state rows pad to the
-    # chunk-aligned count so the scan can reshape them into (m, chunk, ...)
+    # change the canonical padding); chunked, per-client state is allocated
+    # blocked, (m, chunk, ...) rows padded to whole blocks, so each block of
+    # the client pass reads and writes one contiguous slab of it in place
     chunk = (cfg.chunk_size
              if cfg.chunk_size is not None and cfg.chunk_size < n else None)
-    n_rows = chunking.n_blocks(n, chunk) * chunk if chunk else n
+    n_rows = (chunking.n_blocks(n, chunk), chunk) if chunk else n
     state_dt = (jnp.bfloat16 if cfg.state_dtype == "bfloat16"
                 else jnp.float32)
     # static privacy switch: only the mechanism *name* specializes the
@@ -419,8 +421,8 @@ def _make_sim_fns(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
 
     def init_carry(init_params):
         # message-space state rides in the scan carry (inside FLState): the
-        # flat (n_rows, D) EF matrix (dense/SparseEF, fp32/bf16) and, for
-        # control-variate algorithms, the (n_rows, D) ctrl matrix + (D,)
+        # (*n_rows, D) EF matrix (dense/SparseEF, fp32/bf16) and, for
+        # control-variate algorithms, the (*n_rows, D) ctrl matrix + (D,)
         # server control variate.
         state0 = fl_server.init_fl_state(
             init_params, n, algo=algo, use_ef=comp_active,

@@ -11,7 +11,8 @@ triple (``core.algorithms.get_algorithm``):
 All message-space state is flat: per-client EF error is an (N, D) matrix,
 downlink EF a (D,) vector, and SCAFFOLD's per-client control variates an
 (N, D) matrix (``FLState.ctrl``) with the server control variate as the
-algorithm state — exactly the scan-carry layout of the compiled engine.
+algorithm state — the scan-carry layout of the compiled engine, which for a
+chunked client pass holds the per-client rows as (m, chunk, D) blocks.
 Compression comes from ``core.compression.get_compressor`` (registry names +
 traced :class:`CompressionParams`); the old opaque-callable compressor and
 the per-leaf EF branch were removed after their deprecation release.
@@ -68,7 +69,8 @@ def flatten_clients(tree: PyTree) -> Tuple[jnp.ndarray, Callable]:
 @dataclasses.dataclass
 class FLState:
     params: PyTree
-    client_error: Any  # (N, D) uplink EF matrix | SparseEF (N, S) | None
+    client_error: Any  # (N, D) uplink EF matrix | SparseEF (N, S) | None;
+    #                    (m, chunk) rows in the engine's chunked pass
     server_error: Optional[jnp.ndarray]   # (D,) downlink EF state, or None
     server_opt: Any    # algorithm server state: SlowMoState | ServerOptState
     #                    | (D,) SCAFFOLD server control variate | None
@@ -87,7 +89,8 @@ def init_fl_state(params: PyTree, n_clients: int, *,
                   use_ef: bool = False, double_ef: bool = False,
                   server: Optional[str] = None, ef_mode: str = "dense",
                   ef_slots: Optional[int] = None, state_dtype=jnp.float32,
-                  n_rows: Optional[int] = None) -> FLState:
+                  n_rows: Union[int, Tuple[int, int], None] = None
+                  ) -> FLState:
     """``use_ef`` allocates the per-client EF state, ``double_ef`` the (D,)
     downlink EF vector; the algorithm decides its own server state and
     whether an (N, D) control-variate matrix joins the carry.
@@ -96,9 +99,10 @@ def init_fl_state(params: PyTree, n_clients: int, *,
     :class:`SparseEF` of ``ef_slots`` (value, index) pairs per client
     (O(N·S), top-k compressor family); ``state_dtype`` (fp32/bf16) is the
     storage dtype of the message-space client state (EF values and SCAFFOLD
-    control variates — compute stays fp32); ``n_rows`` over-allocates the
-    per-client state to the chunk-padded row count of the chunked client
-    pass (defaults to ``n_clients``)."""
+    control variates — compute stays fp32); ``n_rows`` sizes the
+    per-client state for the chunked client pass: the chunk-padded row
+    count, or the blocked ``(m, chunk)`` row shape, which keeps each
+    block's rows contiguous (defaults to ``n_clients``)."""
     if server is not None:
         warnings.warn(
             "init_fl_state(server=...) is deprecated; pass algo="
@@ -110,16 +114,17 @@ def init_fl_state(params: PyTree, n_clients: int, *,
     a = algorithms.get_algorithm(algo)
     d = flat_dim(params)
     rows = n_clients if n_rows is None else n_rows
+    rows = rows if isinstance(rows, tuple) else (rows,)
     if use_ef and ef_mode == "sparse":
         slots = default_ef_slots(d) if ef_slots is None else ef_slots
         client_error = error_feedback.init_sparse_error(rows, d, slots,
                                                         state_dtype)
     elif use_ef:
-        client_error = jnp.zeros((rows, d), state_dtype)
+        client_error = jnp.zeros((*rows, d), state_dtype)
     else:
         client_error = None
     server_error = jnp.zeros(d, jnp.float32) if double_ef else None
-    ctrl = jnp.zeros((rows, d), state_dtype) if a.uses_ctrl else None
+    ctrl = jnp.zeros((*rows, d), state_dtype) if a.uses_ctrl else None
     return FLState(params, client_error, server_error,
                    a.init_algo_state(params), ctrl, 0)
 
@@ -197,7 +202,9 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
     constant-folds transcendentals (e.g. QSGD's ``log2``) with a different
     evaluator than the compiled scan program, costing the last ulp. With
     ``chunk_size`` the per-client state (EF/ctrl) must be allocated with
-    ``init_fl_state(n_rows=ceil(N/chunk) * chunk)``.
+    ``init_fl_state(n_rows=(m, chunk))``, ``m = ceil(N/chunk)`` (the
+    engine's layout), or flat with ``n_rows=m * chunk``; the round returns
+    it in the layout it was given.
 
     The algorithm *name* is static; every hyperparameter rides the traced
     ``aparams`` (a vmappable sweep axis). Registry compression
@@ -256,7 +263,7 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
     ef = state.client_error
     sparse_ef = isinstance(ef, SparseEF)
     if sparse_ef:
-        state_dt, ef_slots = ef.values.dtype, ef.values.shape[1]
+        state_dt, ef_slots = ef.values.dtype, ef.values.shape[-1]
     else:
         state_dt, ef_slots = (ef.dtype if ef is not None else jnp.float32), 0
 
@@ -326,8 +333,11 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
     # compression, then canonical partial sums. Every client compresses
     # (and accrues EF error) whether or not it is scheduled; participation
     # gates the sums only (plus, under gate_ef, the EF advancement). The
-    # unchunked pass is this function called once.
-    def client_block(ids, batches_b, part_b, sw_b, ef_b, ctrl_b):
+    # unchunked pass is this function called once; the chunked pass gives
+    # ``put_ef``, which writes the block's new EF into the carried state
+    # and whose result is returned in its place.
+    def client_block(ids, batches_b, part_b, sw_b, ef_b, ctrl_b,
+                     put_ef=None):
         with jax.named_scope("fl.aggregate"):
             valid = (ids < n).astype(jnp.float32)
         with jax.named_scope("fl.local_update"):
@@ -391,6 +401,14 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
                         keep.reshape((-1,) + (1,) * (nw.ndim - 1)), nw, old),
                     new_ef_b, ef_b)
 
+        if put_ef is not None and new_ef_b is not None:
+            # the write-back goes before the block's sums, held there by a
+            # barrier: XLA would otherwise schedule it after them and keep
+            # the corrected (chunk, D) message alive through the sums (on
+            # the TPU at D = 1.24e8, 0.2 GiB more scratch)
+            flat, new_ef_b = lax.optimization_barrier((flat,
+                                                       put_ef(new_ef_b)))
+
         w = valid if part_b is None else part_b
         if priv is not None:
             # privacy acts on the wire message (post-EF/compression): clip,
@@ -431,25 +449,32 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
         chunk = chunk_size
         m = chunking.n_blocks(n, chunk)
         npad = m * chunk
-        _check_state_rows(ef, state.ctrl, npad, "chunk_size")
+        ef_rows = _state_rows(ef, "client_error", ((m, chunk), (npad,)),
+                              "chunk_size")
+        ctrl_rows = _state_rows(state.ctrl, "ctrl", ((m, chunk), (npad,)),
+                                "chunk_size")
         with jax.named_scope("fl.aggregate"):
             part_pad = (None if part is None
                         else jnp.pad(part, (0, npad - n)).reshape(m, chunk))
             sw_pad = (None if sw is None
                       else jnp.pad(sw, (0, npad - n)).reshape(m, chunk))
-        # per-client state stays (npad, ...) in the carry and each block
-        # reads and writes its rows in place: a (m, chunk, ...) view would
-        # cost a relayout copy of the whole state on the TPU's tiled layout
+        # per-client state rides the scan as (m, chunk, ...): a block reads
+        # its rows by an index on the leading, untiled axis and writes them
+        # back in place, one contiguous slab. In a flat (npad, ...) array
+        # on the TPU's tiled layout every tile holds rows of several
+        # blocks, so each block's write would rewrite the whole state. The
+        # engine allocates the blocked shape; a flat state (a direct
+        # caller's) is reshaped here and back on the way out.
         @jax.named_scope("fl.client_state")
         def rows(st, b):
             return jax.tree.map(
-                lambda x: lax.dynamic_slice_in_dim(x, b * chunk, chunk), st)
+                lambda x: lax.dynamic_index_in_dim(x, b, keepdims=False), st)
 
         @jax.named_scope("fl.client_state")
         def put_rows(st, new, b):
             return jax.tree.map(
-                lambda x, y: lax.dynamic_update_slice_in_dim(x, y, b * chunk,
-                                                             0), st, new)
+                lambda x, y: lax.dynamic_update_index_in_dim(x, y, b, 0),
+                st, new)
 
         def scan_block(carry, xs):
             ef_all, ctrl_all = carry
@@ -459,25 +484,28 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
                 batches_b = (batch_fn(ids) if batch_fn is not None
                              else jax.tree.map(lambda x: x[ids],
                                                stacked_batches))
-            psums, new_ef_b, new_ctrl_b = client_block(
+            psums, new_ef_all, new_ctrl_b = client_block(
                 ids, batches_b, part_b, sw_b, rows(ef_all, b),
-                rows(ctrl_all, b))
-            return (put_rows(ef_all, new_ef_b, b),
-                    put_rows(ctrl_all, new_ctrl_b, b)), psums
+                rows(ctrl_all, b), put_ef=lambda e: put_rows(ef_all, e, b))
+            return (new_ef_all, put_rows(ctrl_all, new_ctrl_b, b)), psums
 
         # the loop stacks the block partials for the fold below: work
         # under no stage of its own is aggregation's
         with jax.named_scope("fl.aggregate"):
             (client_error, new_ctrl), psums_m = lax.scan(
-                scan_block, (ef, state.ctrl),
+                scan_block, (_with_rows(ef, (m, chunk)),
+                             _with_rows(state.ctrl, (m, chunk))),
                 (jnp.arange(m, dtype=jnp.int32), part_pad, sw_pad))
+        client_error = _with_rows(client_error, ef_rows)
+        new_ctrl = _with_rows(new_ctrl, ctrl_rows)
         # block partials are aligned subtrees of the full canonical tree, so
         # folding them canonically reproduces the unchunked sum bit-for-bit
         with jax.named_scope("fl.aggregate"):
             totals = {k: chunking.canonical_sum(v)
                       for k, v in psums_m.items()}
     else:
-        _check_state_rows(ef, state.ctrl, n, "the client count")
+        _state_rows(ef, "client_error", ((n,),), "the client count")
+        _state_rows(state.ctrl, "ctrl", ((n,),), "the client count")
         with jax.named_scope("fl.data"):
             ids = jnp.arange(n, dtype=jnp.int32)
             batches = (batch_fn(ids) if batch_fn is not None
@@ -596,16 +624,24 @@ def _kernel_sign_ef(flat: jnp.ndarray, e: jnp.ndarray):
     return kernel_ops.sign_ef_rows(flat, e)
 
 
-def _check_state_rows(ef, ctrl, rows: int, why: str) -> None:
-    for name, st in (("client_error", ef), ("ctrl", ctrl)):
-        if st is None:
-            continue
-        got = jax.tree.leaves(st)[0].shape[0]
-        if got != rows:
-            raise ValueError(
-                f"FLState.{name} has {got} rows but {why} requires {rows}; "
-                "allocate it with init_fl_state(n_rows=...) matching the "
-                "chunk-padded client count")
+def _state_rows(st, name: str, allowed, why: str):
+    """The row shape (every axis but the last) of per-client state ``st``,
+    checked against the ``allowed`` row shapes; None without state."""
+    if st is None:
+        return None
+    got = jax.tree.leaves(st)[0].shape[:-1]
+    if got not in allowed:
+        raise ValueError(
+            f"FLState.{name} has rows {got} but {why} requires "
+            f"{' or '.join(map(str, allowed))}; allocate it with "
+            "init_fl_state(n_rows=...) matching the chunked client pass")
+    return got
+
+
+def _with_rows(st, rows):
+    """Per-client state with its row axes reshaped to ``rows`` (no-op when
+    they already are)."""
+    return jax.tree.map(lambda x: x.reshape(*rows, x.shape[-1]), st)
 
 
 def _global_norm(tree: PyTree) -> jnp.ndarray:

@@ -70,12 +70,16 @@ def test_canonical_sum_weighted_matches_masked():
 # ---------------------------------------------------------------------------
 def _round_outputs(name, chunk, *, ef_mode="dense", state_dtype=jnp.float32,
                    algo="fedavg", double_ef=False, with_part=False,
-                   privacy=None):
+                   privacy=None, blocked=False, gate_ef=False):
+    """``blocked`` allocates a chunked pass's state as the engine does,
+    (m, chunk, ...) rows; ``gate_ef`` adds the fault mode's EF gate and
+    empty-round guard (needs ``with_part``)."""
     params, loss_fn, make_batches, _ = _problem()
     batches = jax.tree.map(jnp.asarray, make_batches(0, N))
     # chunk >= N degenerates to the unchunked pass (N state rows)
     eff = chunk if chunk is not None and chunk < N else None
-    rows = chunking.n_blocks(N, eff) * eff if eff else N
+    m = chunking.n_blocks(N, eff) if eff else None
+    rows = ((m, eff) if blocked else m * eff) if eff else N
     comp = name != "none"
     state = server.init_fl_state(
         params, N, algo=algo, use_ef=comp, double_ef=comp and double_ef,
@@ -89,13 +93,24 @@ def _round_outputs(name, chunk, *, ef_mode="dense", state_dtype=jnp.float32,
     if with_part:
         part = (jnp.arange(N) % 2).astype(jnp.float32)
         kwargs.update(participation=part)
+    if gate_ef:
+        kwargs.update(gate_ef=True, guard_empty=True)
     if privacy is not None:
         kwargs.update(privacy=privacy,
                       pparams=privacy_params(clip=0.5, sigma=0.3),
                       privacy_key=jax.random.PRNGKey(11))
     fn = jax.jit(functools.partial(server.fl_round, **kwargs))
     new_state, metrics = fn(state, batches)
+    # the round hands per-client state back in the layout it was given
+    for old, new in ((state.client_error, new_state.client_error),
+                     (state.ctrl, new_state.ctrl)):
+        assert jax.tree.map(jnp.shape, old) == jax.tree.map(jnp.shape, new)
     return new_state, metrics
+
+
+def _state_rows(x, dtype=None):
+    """The first N client rows of per-client state, flat or blocked."""
+    return np.asarray(x, dtype).reshape(-1, x.shape[-1])[:N]
 
 
 def _assert_rounds_equal(a, b):
@@ -109,27 +124,43 @@ def _assert_rounds_equal(a, b):
     if sa.client_error is not None:
         if isinstance(sa.client_error, SparseEF):
             np.testing.assert_array_equal(
-                np.asarray(sa.client_error.values[:N], jnp.float32),
-                np.asarray(sb.client_error.values[:N], jnp.float32))
+                _state_rows(sa.client_error.values, jnp.float32),
+                _state_rows(sb.client_error.values, jnp.float32))
             np.testing.assert_array_equal(
-                np.asarray(sa.client_error.indices[:N]),
-                np.asarray(sb.client_error.indices[:N]))
+                _state_rows(sa.client_error.indices),
+                _state_rows(sb.client_error.indices))
         else:
             np.testing.assert_array_equal(
-                np.asarray(sa.client_error[:N], jnp.float32),
-                np.asarray(sb.client_error[:N], jnp.float32))
+                _state_rows(sa.client_error, jnp.float32),
+                _state_rows(sb.client_error, jnp.float32))
     if sa.ctrl is not None:
-        np.testing.assert_array_equal(np.asarray(sa.ctrl[:N], jnp.float32),
-                                      np.asarray(sb.ctrl[:N], jnp.float32))
+        np.testing.assert_array_equal(_state_rows(sa.ctrl, jnp.float32),
+                                      _state_rows(sb.ctrl, jnp.float32))
     if sa.server_error is not None:
         np.testing.assert_array_equal(np.asarray(sa.server_error),
                                       np.asarray(sb.server_error))
 
 
-@pytest.mark.parametrize("name", compression.compressor_names())
-def test_chunked_round_bitwise_parity(name):
-    _assert_rounds_equal(_round_outputs(name, CHUNK),
-                         _round_outputs(name, None))
+# every compressor on a flat chunk-padded state, then the engine's blocked
+# (m, chunk, ...) state under each kind of per-client state it carries
+_PARITY_CASES = (
+    [pytest.param(name, {}, id=name)
+     for name in compression.compressor_names()]
+    + [pytest.param("topk", dict(blocked=True), id="blocked-topk"),
+       pytest.param("topk", dict(blocked=True, state_dtype=jnp.bfloat16),
+                    id="blocked-topk-bf16"),
+       pytest.param("topk", dict(blocked=True, ef_mode="sparse"),
+                    id="blocked-topk-sparse_ef"),
+       pytest.param("topk", dict(blocked=True, algo="scaffold"),
+                    id="blocked-topk-scaffold"),
+       pytest.param("topk", dict(blocked=True, with_part=True, gate_ef=True),
+                    id="blocked-topk-gate_ef")])
+
+
+@pytest.mark.parametrize("name,opts", _PARITY_CASES)
+def test_chunked_round_bitwise_parity(name, opts):
+    _assert_rounds_equal(_round_outputs(name, CHUNK, **opts),
+                         _round_outputs(name, None, **opts))
 
 
 @pytest.mark.parametrize("name", ["topk", "randk", "rtopk"])

@@ -132,3 +132,37 @@ def test_fleet_engine_step_fits_v5e(one_chip, monkeypatch):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < V5E_HBM_BYTES, total
+
+
+def test_chunked_ef_state_is_written_in_place_per_block(one_chip,
+                                                          monkeypatch):
+    """The chunked client pass carries per-client EF rows as (blocks,
+    chunk, D): each block writes only its own slab, at an aligned leading
+    index. A flat (N, D) state on the TPU's (8, 128) tiles would put rows
+    of every block in each tile, so each block's write would rewrite the
+    whole state."""
+    monkeypatch.setattr(ops, "resolve_mode", lambda mode: "pallas")
+    jax.clear_caches()
+    n, chunk, d = 8, 2, (1 << 20) + 3 * 128
+    params, loss_fn, _, w_star = make_linear_problem(d=d)
+    cfg = rt.SimConfig(n_devices=n, n_scheduled=4, rounds=1,
+                       chunk_size=chunk, datagen=make_linear_datagen(w_star),
+                       compression="topk")
+    wcfg = rt.wireless.WirelessConfig(n_devices=cfg.n_devices)
+    _, _, engine = rt._make_sim_fns(cfg, wcfg, loss_fn, False)
+    args = (jax.random.PRNGKey(cfg.seed), rt.wireless.channel_params(wcfg),
+            rt._resolve_cparams(cfg, params), rt._resolve_aparams(cfg),
+            params)
+    arg_shapes = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                              args)
+    text = jax.jit(engine).lower(*arg_shapes, None, None).compile().as_text()
+    writes = [ln for ln in text.splitlines()
+              if " dynamic-update-slice(" in ln and "fl.client_state" in ln]
+    written = [tuple(int(x) for x in re.search(
+        r" = f32\[([\d,]+)\]", ln).group(1).split(",")) for ln in writes]
+    assert (n // chunk, chunk, d) in written, written
+    for shape, ln in zip(written, writes):
+        assert not (len(shape) == 2 and shape[0] > chunk), ln
+        if shape == (n // chunk, chunk, d):
+            aligned = re.search(r'"is_index_aligned":\[(\w+)', ln).group(1)
+            assert aligned == "true", ln
