@@ -74,7 +74,7 @@ def _make_cfg(n: int, rounds: int, datagen, *, chunk=CHUNK, **kw
 def _temp_bytes(cfg: rt.SimConfig, loss_fn, params) -> int:
     """XLA temp-buffer estimate for the compiled engine (compile only)."""
     wcfg = rt.wireless.WirelessConfig(n_devices=cfg.n_devices)
-    _, _, engine = rt._make_sim_fns(cfg, wcfg, loss_fn, False)
+    engine = rt._make_sim_fns(cfg, wcfg, loss_fn, False).engine
     lowered = jax.jit(engine).lower(
         jax.random.PRNGKey(cfg.seed), rt.wireless.channel_params(wcfg),
         rt._resolve_cparams(cfg, params), rt._resolve_aparams(cfg),

@@ -213,9 +213,9 @@ def phase_fleet():
     timed_run(cfg, loss_fn, params)  # first call: the engine's own compile
     _, logs, dt = timed_run(cfg, loss_fn, params)
     d = rt.fl_server.flat_dim(params)
-    price = float(rt.message_bits_jax(cfg.compression,
-                                      rt._resolve_cparams(cfg, params),
-                                      cfg.model_bits, d))
+    price = float(rt.link.message_bits_jax(cfg.compression,
+                                           rt._resolve_cparams(cfg, params),
+                                           cfg.model_bits, d))
     want = logs.n_scheduled.astype(np.float64) * price
     finite = bool(np.all(np.isfinite(logs.loss)))
     say(f"phase 3 fleet N={cfg.n_devices} scheduled={cfg.n_scheduled} "

@@ -58,8 +58,9 @@ from repro.core.compression.registry import CompressionParams
 from repro.core.faults import FaultParams
 from repro.core.hierarchy import HFLConfig, hfl_geometry_xy_jax
 from repro.fl import server as fl_server
+from repro.fl.link import message_bits_jax
 from repro.fl.runtime import (ENGINE_STATS, _ENGINE_CACHE, _cached,
-                              message_bits_jax, stack_batches)
+                              stack_batches)
 
 PyTree = Any
 

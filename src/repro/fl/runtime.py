@@ -21,11 +21,10 @@ An entire multi-round simulation compiles into **one XLA program**:
   client pass is chunked) and its second uplink message doubles the
   priced bits-on-the-wire;
 * ``run_simulation_scan`` wraps one round as a ``lax.scan`` body whose carry
-  is ``(FLState, wall_clock, ages, update_norms, avg_snr)`` — the last being
-  the per-device time-averaged-SNR EMA behind true proportional-fair;
-  latency accounting (synchronous round = max over scheduled devices) and
-  the age recursion live *inside* the scan; per-round logs come back
-  stacked;
+  is ``(FLState, LinkState)``; the wireless part of a round — channel draw,
+  pricing, retransmissions, the synchronous round clock, fault logs, DP
+  accounting and the :class:`~repro.fl.link.RoundOut` logs, which come
+  back stacked — is ``fl/link.py``'s, shared with the hierarchical engine;
 * compression is first-class (``core/compression/registry.py``): the
   compressor *name* is static, its continuous parameters travel as a traced
   :class:`~repro.core.compression.registry.CompressionParams`, per-client EF
@@ -49,7 +48,7 @@ An entire multi-round simulation compiles into **one XLA program**:
   each cluster runs the registry scheduling policy over its members, EF and
   SCAFFOLD ctrl state ride the HFL scan carry, and the periodic SBS->MBS
   sync ships a separately compressed and priced backhaul payload;
-* each stage of a flat round runs under a ``jax.named_scope``
+* each stage of a round runs under a ``jax.named_scope``
   (``fl.channel``, ``fl.schedule``, ``fl.data``, ``fl.local_update``,
   ``fl.compress``, ``fl.client_state``, ``fl.privacy``, ``fl.aggregate``,
   ``fl.server_update``, ``fl.log``), which names its operations in the
@@ -63,7 +62,7 @@ An entire multi-round simulation compiles into **one XLA program**:
   the scan in-place on the parameter buffers);
 * decentralized gossip and the fog hybrid (``fl/decentralized.py``) are
   built on the same pattern and plug into this module's engine cache,
-  ``ENGINE_STATS`` trace counter, and :func:`message_bits_jax` payload
+  ``ENGINE_STATS`` trace counter, and ``link.message_bits_jax`` payload
   pricing — their mixing matrix ``W`` is one more *traced* argument, so a
   topology grid is a sweep axis like any other.
 
@@ -80,7 +79,8 @@ import dataclasses
 import functools
 import itertools
 import warnings
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -101,6 +101,7 @@ from repro.core.privacy.registry import (PrivacyParams, privacy_params,
                                          stack_privacy_params)
 from repro.core.hierarchy import (HFLConfig, broadcast_to_clients,
                                   hfl_geometry_jax, inter_cluster_average)
+from repro.fl import link
 from repro.fl import server as fl_server
 
 PyTree = Any
@@ -296,21 +297,12 @@ class SimLogs:
     def to_round_logs(self) -> List[RoundLog]:
         if self.loss.ndim != 1:
             raise ValueError("to_round_logs needs unbatched (rounds,) logs")
-
-        def opt(field, t, cast, default=0):
-            return cast(field[t]) if field is not None else cast(default)
-        return [RoundLog(t, float(self.latency_s[t]), float(self.loss[t]),
-                         int(self.n_scheduled[t]), self.participation[t],
-                         float(self.uplink_bits[t]), float(self.comm_s[t]),
-                         float(self.comp_s[t]),
-                         opt(self.downlink_bits, t, float),
-                         opt(self.n_survived, t, int),
-                         opt(self.n_dropped, t, int),
-                         opt(self.retransmissions, t, float),
-                         opt(self.staleness_mean, t, float),
-                         opt(self.epsilon, t, float, float("inf")),
-                         opt(self.delta, t, float, 1.0),
-                         opt(self.mask_bits, t, float))
+        # a field an older caller left None keeps RoundLog's default
+        given = {f.name: getattr(self, f.name)
+                 for f in dataclasses.fields(self)
+                 if getattr(self, f.name) is not None}
+        return [RoundLog(t, **{k: v[t] if v.ndim > 1 else v[t].item()
+                               for k, v in given.items()})
                 for t in range(self.loss.shape[0])]
 
 
@@ -332,21 +324,6 @@ def _policy_cfg(cfg: SimConfig, wcfg: wireless.WirelessConfig
         n_subchannels=wcfg.n_subchannels)
 
 
-def message_bits_jax(compression_name: str, cparams: CompressionParams,
-                     model_bits: float, d_model: int) -> jnp.ndarray:
-    """Simulated bits-on-the-wire of one model-sized message: ``model_bits``
-    scaled by the compressor's bits-per-parameter rate on the actual d-dim
-    message (data-independent, so a round can be priced *before*
-    transmission). ``"none"`` sends exactly ``model_bits``. Shared pricing
-    model of the flat, HFL, and gossip/fog engines
-    (``fl/decentralized.py``)."""
-    if compression_name == "none":
-        return jnp.float32(model_bits)
-    payload_scale = model_bits / (32.0 * d_model)
-    return payload_scale * compression.uplink_bits_jax(
-        compression_name, cparams, d_model)
-
-
 def _resolve_cparams(cfg: SimConfig, init_params) -> CompressionParams:
     if cfg.compression_params is not None:
         return cfg.compression_params
@@ -366,11 +343,67 @@ def _resolve_pparams(cfg: SimConfig) -> PrivacyParams:
     return privacy_lib.default_privacy_params()
 
 
+class _Round(NamedTuple):
+    """A round builder's parts, which one frame runs as the scanned
+    ``engine`` or round by round (``host_step``). Each builder keeps its
+    own scan body: JAX's PRNG lowering names the ops of a key split after
+    the function that first makes it, so the flat engine's compiled program
+    stays the same only while ``_make_sim_fns``' own closures split the
+    run and round keys."""
+    cfg: SimConfig
+    init_carry: Callable   # init_params -> scan carry
+    # (chan, cparams, aparams, extra, fparams, pparams, pol_w, site,
+    #  k_rounds, eval_batch) -> step(carry, (t, batches))
+    make_step: Callable
+    scan: Callable         # the engine's body, with the args split
+    site: Callable         # (key, chan) -> placement: distances / geometry
+    params_of: Callable    # (carry, site) -> the global model
+    n_extra: int = 0       # engine-specific traced args (HFL: bh_rate)
+    mix: bool = False      # a traced policy-mixture weight closes the axes
+
+    def _split(self, rest):
+        # the engine-specific args come first, then the optional traced
+        # axes in a fixed relative order — fparams, pparams, pol_w — each
+        # present only where its static switch is on; the shared trailing
+        # args close the list
+        rest = list(rest)
+        extra = tuple(rest.pop(0) for _ in range(self.n_extra))
+        fparams = rest.pop(0) if self.cfg.faults is not None else None
+        pparams = rest.pop(0) if self.cfg.privacy != "none" else None
+        pol_w = rest.pop(0) if self.mix else None
+        return extra, fparams, pparams, pol_w, rest
+
+    @property
+    def engine(self) -> Callable:
+        """The full scanned run: ``(key, chan, cparams, aparams, *extra,
+        [fparams], [pparams], [pol_w], init_params, batches, eval_batch)
+        -> (final params, stacked RoundOut)``."""
+        def engine(key, chan, cparams, aparams, *rest):
+            ENGINE_STATS["traces"] += 1  # python side effect: trace only
+            extra, fparams, pparams, pol_w, shared = self._split(rest)
+            return self.scan(key, chan, cparams, aparams, extra, fparams,
+                             pparams, pol_w, *shared)
+        return engine
+
+    @property
+    def host_step(self) -> Callable:
+        """One round with the run-specific values as arguments: ``(chan,
+        cparams, aparams, *extra, [fparams], [pparams], site, k_rounds,
+        eval_batch, carry, xs) -> (carry, RoundOut)``."""
+        def host_step(chan, cparams, aparams, *rest):
+            extra, fparams, pparams, _, shared = self._split(rest)
+            site, k_rounds, eval_batch, carry, xs = shared
+            return self.make_step(chan, cparams, aparams, extra, fparams,
+                                  pparams, None, site, k_rounds,
+                                  eval_batch)(carry, xs)
+        return host_step
+
+
 def _make_sim_fns(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
                   has_eval: bool,
-                  policy_axis: Optional[Tuple[str, ...]] = None):
-    """Shared round logic for both engines. Returns
-    ``(init_carry, make_step, engine)``; ``engine`` is the full scanned run.
+                  policy_axis: Optional[Tuple[str, ...]] = None) -> _Round:
+    """The flat round: ``fl_round``'s client pass inside the shared
+    wireless round (``fl/link.py``).
 
     ``policy_axis`` switches the policy from a static name (``cfg.policy``)
     to a *traced* axis: the engine takes an extra one-hot weight vector
@@ -429,109 +462,53 @@ def _make_sim_fns(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
             double_ef=comp_active and cfg.double_ef, ef_mode=cfg.ef_mode,
             ef_slots=cfg.ef_slots, state_dtype=state_dt, n_rows=n_rows)
         state0 = dataclasses.replace(state0, round=jnp.int32(0))
-        carry = (state0, jnp.float32(0.0), jnp.zeros(n, jnp.float32),
-                 jnp.ones(n, jnp.float32), jnp.zeros(n, jnp.float32))
-        if faults_on:
-            # churn availability (everyone starts online), Gauss-Markov
-            # complex fading state, and per-client staleness counters
-            carry = carry + (jnp.ones(n, dtype=bool),
-                             jnp.zeros((n, 2), jnp.float32),
-                             jnp.zeros(n, jnp.float32))
-        if dp_on:
-            # Renyi accountant ledger (one slot per order in ALPHAS),
-            # appended *last* so the fault triple keeps its positions
-            carry = carry + (jnp.zeros(len(privacy_lib.ALPHAS),
-                                       jnp.float32),)
-        return carry
+        return state0, link.LinkState.init(n, faults_on, dp_on)
 
     def make_step(chan: wireless.ChannelParams, cparams: CompressionParams,
-                  aparams: AlgoParams, fparams, pparams, pol_w,
+                  aparams: AlgoParams, extra, fparams, pparams, pol_w,
                   dist: jnp.ndarray, k_rounds: jax.Array, eval_batch):
+        lk = link.Link(
+            cfg, algo.uplink_factor, priv, chan, dist,
+            lambda snr: wireless.shannon_rate_jax(
+                snr, chan.bandwidth_hz / cfg.n_scheduled),
+            n - 1, fparams, pparams)
+
+        def z_eff(n_surv):
+            # secagg_dp's local field noise aggregates to an effective
+            # multiplier sigma * sqrt(survivors); central dp uses sigma
+            if priv.dp_local:
+                return pparams.sigma * jnp.sqrt(jnp.maximum(n_surv, 1.0))
+            return pparams.sigma
+
         def step(carry, xs):
-            if dp_on:
-                carry, rdp = carry[:-1], carry[-1]
-            if faults_on:
-                state, clock, ages, norms, avg_snr, avail, fad, stal = carry
-            else:
-                state, clock, ages, norms, avg_snr = carry
+            state, ls = carry
             t, batches = xs
             kt = jax.random.fold_in(k_rounds, t)
             kf, kc, kp, kn, kz = jax.random.split(kt, 5)
             if priv_on:
-                # fold-tagged so the five legacy streams above are
-                # untouched — privacy="none" is bitwise the old engine
+                # fold-tagged so the five streams above are untouched —
+                # privacy="none" draws what an engine without it draws
                 k_priv = jax.random.fold_in(kt, privacy_lib.PRIVACY_FOLD)
             if cfg.datagen is not None:
                 # per-round data key, derived only on the datagen path so
                 # pre-stacked runs keep their exact randomness stream
                 kd = jax.random.fold_in(kt, DATAGEN_FOLD)
                 batches = functools.partial(cfg.datagen, kd)
-
-            with jax.named_scope("fl.channel"):
-                if faults_on:
-                    # temporally correlated fading replaces the i.i.d.
-                    # draw; round 0 draws the stationary distribution so
-                    # rho=0 recovers the i.i.d. Rayleigh marginal
-                    fad, fading = faults_lib.gauss_markov_fading(
-                        fparams, kt, fad, t)
-                else:
-                    fading = wireless.sample_fading_jax(kf, n)
-                snr_lin = wireless.snr_jax(dist, fading, chan)
-                rates = wireless.shannon_rate_jax(
-                    snr_lin, chan.bandwidth_hz / cfg.n_scheduled)
-                comp_lat = cfg.comp_latency_s * jax.random.exponential(
-                    kc, (n,))
-                if faults_on:
-                    # heavy-tailed straggler tail on top of the
-                    # exponential base
-                    comp_lat = comp_lat * faults_lib.straggler_multiplier(
-                        fparams, kt, n)
-                # uplink pricing: the simulated payload is model_bits
-                # scaled by the compressor's bits-per-parameter rate on the
-                # actual d-dim message (data-independent, so the policies
-                # can price the round *before* transmission), times the
-                # algorithm's messages-per-round (SCAFFOLD uplinks delta +
-                # ctrl delta -> 2x). "none" sends exactly model_bits per
-                # message.
-                d_model = fl_server.flat_dim(state.params)
-                payload_scale = cfg.model_bits / (32.0 * d_model)
-                if comp_active:
-                    bits_dev = message_bits_jax(
-                        cfg.compression, cparams, cfg.model_bits,
-                        d_model) * algo.uplink_factor
-                else:
-                    bits_dev = jnp.float32(cfg.model_bits
-                                           * algo.uplink_factor)
-                mask_over = jnp.float32(0.0)
-                if priv_on:
-                    # field modes replace the compressor's rate with dense
-                    # field_bits per coordinate (a masked message is
-                    # incompressible); the pairwise key agreement adds raw
-                    # protocol bits per round — both priced on the uplink
-                    if priv.uses_field:
-                        bits_dev = payload_scale * privacy_lib.uplink_bits_jax(
-                            cfg.privacy, pparams, d_model,
-                            0.0) * algo.uplink_factor
-                    if priv.uses_masks:
-                        mask_over = privacy_lib.mask_bits_jax(cfg.privacy,
-                                                              n - 1)
-                        bits_dev = bits_dev + mask_over
-                comm_lat = wireless.comm_latency_jax(bits_dev, rates)
-                # per-device time-averaged SNR (PF's denominator), seeded
-                # with the first observation
-                avg_snr = jnp.where(t == 0, snr_lin,
-                                    0.9 * avg_snr + 0.1 * snr_lin)
+            d_model = fl_server.flat_dim(state.params)
+            ls, up = lk.uplink(ls, kt, kf, kc, t, d_model, cparams)
 
             with jax.named_scope("fl.schedule"):
+                avail = ls.avail
                 if faults_on:
                     # Gilbert-Elliott churn: offline devices are invisible
                     # to the policy (score-masked view) and unschedulable
                     avail = faults_lib.churn_step(fparams, kt, avail)
 
                 rstate = scheduling.RoundState(
-                    t=t, key=kp, snr_lin=snr_lin, avg_snr=avg_snr,
-                    rates=rates, comm_lat=comm_lat, comp_lat=comp_lat,
-                    ages=ages, update_norms=norms)
+                    t=t, key=kp, snr_lin=up.snr_lin, avg_snr=ls.avg_snr,
+                    rates=up.rates, comm_lat=up.comm_lat,
+                    comp_lat=up.comp_lat, ages=ls.ages,
+                    update_norms=ls.norms)
                 rstate_pol = (scheduling.masked_round_state(rstate, avail)
                               if faults_on else rstate)
                 if policy_fn is not None:
@@ -547,202 +524,83 @@ def _make_sim_fns(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
                 # aggregated now contributes an update stale by the rounds
                 # it sat out (fault mode tracks true per-client staleness;
                 # the faults-off proxy is the pre-update scheduling age)
-                stal_pre = stal if faults_on else ages
-                ages = scheduling.update_ages_jax(ages, mask)
+                stal_pre = ls.stal if faults_on else ls.ages
+                ls = ls._replace(
+                    avail=avail,
+                    ages=scheduling.update_ages_jax(ls.ages, mask))
 
-            with jax.named_scope("fl.channel"):
-                if faults_on:
-                    # mid-round dropout + SNR-threshold decode failure with
-                    # up to max_retries re-priced retransmissions (each
-                    # re-samples the channel and re-bills the payload's
-                    # airtime)
-                    dropped = faults_lib.dropout_draw(fparams, kt, n) & mask
-                    ok = snr_lin >= fparams.snr_min
-                    comm_eff = comm_lat
-                    n_retx = jnp.zeros(n, jnp.float32)
-                    for r in range(1, cfg.max_retries + 1):
-                        fad_r = faults_lib.retry_fading(kt, r, n)
-                        snr_r = wireless.snr_jax(dist, fad_r, chan)
-                        lat_r = wireless.comm_latency_jax(
-                            bits_dev, wireless.shannon_rate_jax(
-                                snr_r, chan.bandwidth_hz / cfg.n_scheduled))
-                        need = ~ok
-                        comm_eff = comm_eff + jnp.where(need, lat_r, 0.0)
-                        n_retx = n_retx + need.astype(jnp.float32)
-                        ok = ok | (snr_r >= fparams.snr_min)
-                    survived = mask & ~dropped & ok
-                    part = survived.astype(jnp.float32)
-                else:
-                    part = mask.astype(jnp.float32)
+            fate, part = lk.retransmit(kt, up, mask)
 
             # staleness-aware algorithms (fedbuff) down-weight old updates;
             # everyone else gets None so the baseline trace is unchanged
             with jax.named_scope("fl.aggregate"):
                 sw = (faults_lib.staleness_weights(aparams, stal_pre)
                       if algo.uses_staleness else None)
+            comp_kw = (dict(compress_fn=compress_fn, cparams=cparams, key=kz)
+                       if comp_active else {})
             fault_kw = (dict(gate_ef=True, guard_empty=True)
                         if faults_on else {})
             priv_kw = (dict(pparams=pparams, privacy_key=k_priv)
                        if priv_on else {})
-            if comp_active:
-                state, metrics = round_fn(
-                    state, batches, aparams=aparams, participation=part,
-                    compress_fn=compress_fn, cparams=cparams, key=kz,
-                    staleness_weights=sw, **fault_kw, **priv_kw)
-                with jax.named_scope("fl.compress"):
-                    ubits = payload_scale * metrics["uplink_bits"]
-                    if priv_on and priv.uses_masks:
-                        # key-agreement overhead for every *scheduled*
-                        # client (agreement precedes the transmission that
-                        # may fail)
-                        ubits = ubits + mask_over * jnp.sum(mask)
-                    if faults_on:
-                        # bill undecoded attempts' airtime too: retries
-                        # plus the final failed payload of never-decoded
-                        # clients
-                        ubits = ubits + bits_dev * jnp.sum(jnp.where(
-                            mask & ~dropped,
-                            n_retx + (~ok).astype(jnp.float32), 0.0))
-            else:
-                state, metrics = round_fn(
-                    state, batches, aparams=aparams, participation=part,
-                    staleness_weights=sw, **fault_kw, **priv_kw)
-                with jax.named_scope("fl.compress"):
-                    if faults_on:
-                        ubits = bits_dev * jnp.sum(jnp.where(
-                            mask & ~dropped, 1.0 + n_retx, 0.0))
-                    else:
-                        ubits = bits_dev * jnp.sum(mask)
+            state, metrics = round_fn(
+                state, batches, aparams=aparams, participation=part,
+                staleness_weights=sw, **comp_kw, **fault_kw, **priv_kw)
+            ubits = lk.bill(up, mask, fate,
+                            metrics["uplink_bits"] if comp_active else None)
 
             with jax.named_scope("fl.channel"):
-                # downlink pricing (always on): the server broadcast of the
-                # global model opens the round — BS power over the full
-                # band, independent fading, slowest scheduled device gates
-                # the sync barrier. With double EF the broadcast is the
-                # compressed server message instead of the raw model.
+                # the server broadcast of the global model opens the round;
+                # with double EF it is the compressed server message
                 if comp_active and cfg.double_ef:
-                    dl_bits = payload_scale * compression.uplink_bits_jax(
+                    dl_bits = up.payload_scale * compression.uplink_bits_jax(
                         cfg.compression, cparams, d_model)
                 else:
                     dl_bits = jnp.float32(cfg.model_bits)
-                dl_rate = wireless.shannon_rate_jax(
-                    wireless.downlink_snr_jax(
-                        dist, faults_lib.downlink_fading(kt, n), chan),
-                    chan.bandwidth_hz)
-                dl_lat = wireless.comm_latency_jax(dl_bits, dl_rate)
-                any_sched = jnp.any(mask)
-                dl_s = jnp.max(jnp.where(mask, dl_lat, 0.0))
-                dl_bits_out = jnp.where(any_sched, dl_bits, jnp.float32(0.0))
-
-                # wall-clock: synchronous round = slowest scheduled device;
-                # the comm/comp breakdown is that bottleneck device's
-                # split. A dropped client stops consuming the round (the
-                # server's deadline machinery already excluded it), a
-                # decode-failed one still burns its airtime.
-                if faults_on:
-                    comm_c = jnp.where(dropped, 0.0, comm_eff)
-                    comp_c = jnp.where(dropped, 0.0, comp_lat)
-                else:
-                    comm_c, comp_c = comm_lat, comp_lat
-                total = comm_c + comp_c
-                slowest = jnp.argmax(jnp.where(mask, total, -jnp.inf))
-                comm_s = jnp.where(any_sched, comm_c[slowest], 0.0)
-                comp_s = jnp.where(any_sched, comp_c[slowest], 0.0)
-                clock = clock + dl_s + comm_s + comp_s
-
+            ls, out = lk.close(ls, kt, up, mask, part, fate, ubits, dl_bits,
+                               z_eff)
             with jax.named_scope("fl.log"):
-                if faults_on:
-                    stal_log = jnp.mean(stal_pre)
-                    stal = jnp.where(survived, 0.0, stal + 1.0)
-                    retx_log = jnp.sum(jnp.where(mask & ~dropped, n_retx,
-                                                 0.0))
-                    n_surv = jnp.sum(survived).astype(jnp.int32)
-                    n_drop = jnp.sum(mask & ~survived).astype(jnp.int32)
-                else:
-                    stal_log = jnp.float32(0.0)
-                    retx_log = jnp.float32(0.0)
-                    n_surv = jnp.sum(mask).astype(jnp.int32)
-                    n_drop = jnp.int32(0)
-
-                # --- (epsilon, delta) accounting: one subsampled-Gaussian
-                # round at sampling fraction survivors/N. secagg_dp's local
-                # field noise aggregates to an effective multiplier
-                # sigma * sqrt(survivors); central dp uses sigma directly.
-                if dp_on:
-                    n_surv_f = jnp.sum(part)
-                    q_frac = n_surv_f / n
-                    if priv.dp_local:
-                        z_eff = pparams.sigma * jnp.sqrt(
-                            jnp.maximum(n_surv_f, 1.0))
-                    else:
-                        z_eff = pparams.sigma
-                    rdp = rdp + privacy_lib.rdp_increment(q_frac, z_eff)
-                    eps = privacy_lib.epsilon_of(rdp)
-                    delta_out = jnp.float32(privacy_lib.DELTA)
-                else:
-                    eps = jnp.float32(jnp.inf)
-                    delta_out = jnp.float32(1.0)
-                mask_bits_out = mask_over * jnp.sum(mask)
-
                 loss = metrics["loss"]
                 if has_eval:
                     loss = loss_fn(state.params, eval_batch)[0]
-            with jax.named_scope("fl.schedule"):
-                # update-aware policies observe last-round delta norms
-                # (proxy)
-                norms = 0.9 * norms + 0.1 * jax.random.exponential(kn, (n,))
-            new_carry = (state, clock, ages, norms, avg_snr)
-            if faults_on:
-                new_carry = new_carry + (avail, fad, stal)
-            if dp_on:
-                new_carry = new_carry + (rdp,)
-            return new_carry, (
-                loss, clock, mask, jnp.sum(mask), ubits, comm_s, comp_s,
-                dl_bits_out, n_surv, n_drop, retx_log, stal_log, eps,
-                delta_out, mask_bits_out)
+            ls, out = lk.finish(ls, kn, out, loss)
+            return (state, ls), out
         return step
 
-    def _scan(key, chan, cparams, aparams, fparams, pparams, pol_w,
+    def site(k_pos, chan):
+        return wireless.sample_positions_jax(k_pos, chan, n)
+
+    def _scan(key, chan, cparams, aparams, extra, fparams, pparams, pol_w,
               init_params, batches_all, eval_batch):
-        ENGINE_STATS["traces"] += 1  # python side effect: runs at trace only
         k_pos, k_rounds = jax.random.split(key)
         with jax.named_scope("fl.channel"):
-            dist = wireless.sample_positions_jax(k_pos, chan, n)
-        step = make_step(chan, cparams, aparams, fparams, pparams, pol_w,
-                         dist, k_rounds, eval_batch)
+            dist = site(k_pos, chan)
+        step = make_step(chan, cparams, aparams, extra, fparams, pparams,
+                         pol_w, dist, k_rounds, eval_batch)
         ts = jnp.arange(cfg.rounds, dtype=jnp.int32)
         with jax.named_scope("fl.client_state"):
             carry0 = init_carry(init_params)
         # under the sharded sweep the carry varies per variant, like key
-        (state, *_), outs = lax.scan(
+        (state, _), outs = lax.scan(
             step, compat.vary_like(carry0, key), (ts, batches_all))
         return state.params, outs
 
-    # the optional traced axes ride in a fixed relative order — fparams,
-    # then pparams, then pol_w — and only the axes this engine's static
-    # switches enable appear in its signature (the three shared trailing
-    # args close the argument list)
-    def engine(key, chan, cparams, aparams, *rest):
-        rest = list(rest)
-        fparams = rest.pop(0) if faults_on else None
-        pparams = rest.pop(0) if priv_on else None
-        pol_w = rest.pop(0) if policy_axis is not None else None
-        init_params, batches_all, eval_batch = rest
-        return _scan(key, chan, cparams, aparams, fparams, pparams, pol_w,
-                     init_params, batches_all, eval_batch)
-
-    return init_carry, make_step, engine
+    return _Round(cfg, init_carry, make_step, _scan, site,
+                  lambda carry, dist: carry[0].params,
+                  mix=policy_axis is not None)
 
 
 def _engine_key(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
                 has_eval: bool, tag: str,
-                policy_axis: Optional[Tuple[str, ...]] = None) -> Tuple:
+                policy_axis: Optional[Tuple[str, ...]] = None,
+                hcfg: Optional[HFLConfig] = None) -> Tuple:
     # continuous channel / compression / algorithm params are traced
     # (ChannelParams / CompressionParams / AlgoParams); everything the trace
     # specializes on must appear here. Compression and the algorithm are
     # keyed by their static *names*, so two equal configs share one compiled
     # engine regardless of hyperparameter values. With a policy mixture the
     # *set of enabled names* replaces the single policy name in the key.
+    # An HFLConfig enters as its static_key(): the traced backhaul_rate_bps
+    # is zeroed out, so a backhaul-rate grid shares one compiled engine.
     return (tag,
             ("mix",) + tuple(policy_axis) if policy_axis is not None
             else cfg.policy,
@@ -752,7 +610,8 @@ def _engine_key(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
             cfg.chunk_size, cfg.ef_mode, cfg.ef_slots, cfg.state_dtype,
             cfg.datagen, cfg.faults is not None, cfg.max_retries,
             cfg.privacy,
-            wcfg.n_subchannels, wcfg.bandwidth_hz, loss_fn, has_eval)
+            wcfg.n_subchannels, wcfg.bandwidth_hz, loss_fn, has_eval,
+            hcfg.static_key() if hcfg is not None else None)
 
 
 _ENGINE_CACHE: Dict[Tuple, Callable] = {}
@@ -792,59 +651,94 @@ def _shard_variants(vengine: Callable, mesh, n_var: int) -> Callable:
                          out_specs=(P(axis), P(axis)))
 
 
+def _make_round(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
+                has_eval: bool, hcfg: Optional[HFLConfig] = None,
+                policy_axis: Optional[Tuple[str, ...]] = None) -> _Round:
+    if hcfg is None:
+        return _make_sim_fns(cfg, wcfg, loss_fn, has_eval, policy_axis)
+    return _make_hfl_fns(cfg, hcfg, wcfg, loss_fn, has_eval)
+
+
 def _get_engine(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
-                has_eval: bool, *, vmapped: bool = False,
+                has_eval: bool, *, hcfg: Optional[HFLConfig] = None,
+                vmapped: bool = False,
                 policy_axis: Optional[Tuple[str, ...]] = None,
                 mesh=None) -> Callable:
+    """The cached compiled engine of the flat round, or of the hierarchical
+    one where ``hcfg`` is given."""
     def make():
-        _, _, engine = _make_sim_fns(cfg, wcfg, loss_fn, has_eval,
-                                     policy_axis)
-        faults_on = cfg.faults is not None
-        priv_on = cfg.privacy != "none"
+        rnd = _make_round(cfg, wcfg, loss_fn, has_eval, hcfg, policy_axis)
+        # the per-variant (traced, vmappable) leading args
+        n_var = (4 + rnd.n_extra + (cfg.faults is not None)
+                 + (cfg.privacy != "none") + rnd.mix)
         if vmapped:
-            n_var = 4 + (policy_axis is not None) + faults_on + priv_on
-            in_axes = (0,) * n_var + (None,) * 3
-            vengine = jax.vmap(engine, in_axes=in_axes)
+            vengine = jax.vmap(rnd.engine, in_axes=(0,) * n_var + (None,) * 3)
             if mesh is not None:
                 vengine = _shard_variants(vengine, mesh, n_var)
             # broadcast init_params can't alias the per-variant outputs, so
             # there is nothing useful to donate on the sweep path.
             return jax.jit(vengine)
+        if hcfg is not None:
+            # the broadcast to (L, ...) cluster models copies the initial
+            # params anyway, so there is no aliasable output buffer
+            return jax.jit(rnd.engine)
         # init_params aliases the returned final params exactly; the
         # wrappers below pass a fresh copy, so donating it is safe and
         # lets XLA run the whole scan in-place on the parameter buffers.
-        return jax.jit(engine, donate_argnums=(4 + faults_on + priv_on,))
+        return jax.jit(rnd.engine, donate_argnums=(n_var,))
 
     return _cached(_ENGINE_CACHE,
                    _engine_key(cfg, wcfg, loss_fn, has_eval,
                                "sweep" if vmapped else "single",
-                               policy_axis) + _mesh_key(mesh), make)
+                               policy_axis, hcfg) + _mesh_key(mesh), make)
 
 
 def _get_host_step(cfg: SimConfig, wcfg: wireless.WirelessConfig, loss_fn,
-                   has_eval: bool) -> Callable:
+                   has_eval: bool,
+                   hcfg: Optional[HFLConfig] = None) -> Callable:
     """Jitted per-round step with the run-specific values (channel params,
-    positions, round key, eval batch) as *arguments*, so the compiled step
+    placement, round key, eval batch) as *arguments*, so the compiled step
     is shared across runs of the same static config (no per-call retrace)."""
-    def make():
-        _, make_step, _ = _make_sim_fns(cfg, wcfg, loss_fn, has_eval)
-        faults_on = cfg.faults is not None
-        priv_on = cfg.privacy != "none"
+    return _cached(
+        _ENGINE_CACHE,
+        _engine_key(cfg, wcfg, loss_fn, has_eval, "host-step", hcfg=hcfg),
+        lambda: jax.jit(_make_round(cfg, wcfg, loss_fn, has_eval,
+                                    hcfg).host_step))
 
-        # optional args in the engines' fixed order: fparams, then pparams
-        def host_step(chan, cparams, aparams, *rest):
-            rest = list(rest)
-            fparams = rest.pop(0) if faults_on else None
-            pparams = rest.pop(0) if priv_on else None
-            dist, k_rounds, eval_batch, carry, xs = rest
-            return make_step(chan, cparams, aparams, fparams, pparams,
-                             None, dist, k_rounds, eval_batch)(carry, xs)
 
-        return jax.jit(host_step)
+def _run_args(cfg: SimConfig, hcfg: Optional[HFLConfig]) -> Tuple:
+    """A single run's traced args after ``aparams``, in the engines'
+    order: HFL's backhaul rate, then the enabled optional axes."""
+    return (((jnp.float32(hcfg.backhaul_rate_bps),) if hcfg is not None
+             else ())
+            + ((cfg.faults,) if cfg.faults is not None else ())
+            + ((_resolve_pparams(cfg),) if cfg.privacy != "none" else ()))
 
-    return _cached(_ENGINE_CACHE,
-                   _engine_key(cfg, wcfg, loss_fn, has_eval, "host-step"),
-                   make)
+
+def _sim_logs(outs: link.RoundOut) -> SimLogs:
+    return SimLogs(**jax.device_get(outs)._asdict())
+
+
+def _run_scan(cfg: SimConfig, wcfg: wireless.WirelessConfig,
+              hcfg: Optional[HFLConfig], chan, loss_fn, init_params: PyTree,
+              batches, eval_batch) -> Tuple[PyTree, SimLogs]:
+    with TraceAnnotation("fl.engine_lookup"):
+        engine = _get_engine(cfg, wcfg, loss_fn, eval_batch is not None,
+                             hcfg=hcfg)
+    with TraceAnnotation("fl.prepare"):
+        key = jax.random.PRNGKey(cfg.seed)
+        if chan is None:
+            chan = wireless.channel_params(wcfg)
+        cparams = _resolve_cparams(cfg, init_params)
+        aparams = _resolve_aparams(cfg)
+        # donated to the flat engine
+        init_copy = jax.tree.map(jnp.array, init_params)
+        args = _run_args(cfg, hcfg)
+    with TraceAnnotation("fl.dispatch"):
+        params, outs = engine(key, chan, cparams, aparams, *args,
+                              init_copy, batches, eval_batch)
+    with TraceAnnotation("fl.fetch_logs"):
+        return params, _sim_logs(outs)
 
 
 def run_simulation_scan(cfg: SimConfig, loss_fn, init_params: PyTree,
@@ -863,30 +757,8 @@ def run_simulation_scan(cfg: SimConfig, loss_fn, init_params: PyTree,
         raise ValueError("run_simulation_scan needs batches= (stack_batches) "
                          "or a SimConfig.datagen")
     wcfg = wcfg or wireless.WirelessConfig(n_devices=cfg.n_devices)
-    with TraceAnnotation("fl.engine_lookup"):
-        engine = _get_engine(cfg, wcfg, loss_fn, eval_batch is not None)
-    with TraceAnnotation("fl.prepare"):
-        key = jax.random.PRNGKey(cfg.seed)
-        chan = wireless.channel_params(wcfg)
-        cparams = _resolve_cparams(cfg, init_params)
-        aparams = _resolve_aparams(cfg)
-        # donated to the engine
-        init_copy = jax.tree.map(jnp.array, init_params)
-        fargs = (cfg.faults,) if cfg.faults is not None else ()
-        pargs = (_resolve_pparams(cfg),) if cfg.privacy != "none" else ()
-    with TraceAnnotation("fl.dispatch"):
-        params, outs = engine(key, chan, cparams, aparams, *fargs, *pargs,
-                              init_copy, batches, eval_batch)
-    with TraceAnnotation("fl.fetch_logs"):
-        (losses, clocks, masks, nsched, ubits, comm_s, comp_s, dl_bits,
-         n_surv, n_drop, retx, stal, eps, dlt, mbits) = jax.device_get(outs)
-        return params, SimLogs(loss=losses, latency_s=clocks,
-                               n_scheduled=nsched, participation=masks,
-                               uplink_bits=ubits, comm_s=comm_s,
-                               comp_s=comp_s, downlink_bits=dl_bits,
-                               n_survived=n_surv, n_dropped=n_drop,
-                               retransmissions=retx, staleness_mean=stal,
-                               epsilon=eps, delta=dlt, mask_bits=mbits)
+    return _run_scan(cfg, wcfg, None, None, loss_fn, init_params, batches,
+                     eval_batch)
 
 
 def run_simulation(cfg: SimConfig, loss_fn, init_params: PyTree,
@@ -910,11 +782,21 @@ def run_simulation(cfg: SimConfig, loss_fn, init_params: PyTree,
     (as ``benchmarks.common.make_lm_problem`` does). An opaque host-side
     ``eval_fn`` (no attribute) is honored as-is and runs on the host loop.
     """
+    wcfg = wcfg or wireless.WirelessConfig(n_devices=cfg.n_devices)
+    return _run(cfg, wcfg, None, wireless.channel_params(wcfg), loss_fn,
+                init_params, sample_client_batches, eval_fn, engine)
+
+
+def _run(cfg: SimConfig, wcfg: wireless.WirelessConfig,
+         hcfg: Optional[HFLConfig], chan, loss_fn, init_params: PyTree,
+         sample_client_batches, eval_fn, engine) -> List[RoundLog]:
+    """The ``engine=`` / eval dispatch of :func:`run_simulation` and
+    :func:`run_hfl`: the scanned engine, or the host loop over the same
+    round step for ``engine="host"`` or an opaque ``eval_fn``."""
     if engine not in (None, "scan", "host"):
         raise ValueError(f"unknown engine {engine!r}; use 'scan' or 'host'")
     if cfg.rounds == 0:
         return []
-    wcfg = wcfg or wireless.WirelessConfig(n_devices=cfg.n_devices)
     eval_batch = getattr(eval_fn, "eval_batch", None) if eval_fn else None
     opaque_eval = eval_fn is not None and eval_batch is None
     if engine == "scan" and opaque_eval:
@@ -923,53 +805,39 @@ def run_simulation(cfg: SimConfig, loss_fn, init_params: PyTree,
             "eval_batch (logged loss becomes loss_fn(params, eval_batch)) "
             "or drop engine= to let the host loop serve the opaque eval_fn")
     if engine == "host" or opaque_eval:
-        return _run_simulation_host(cfg, loss_fn, init_params,
-                                    sample_client_batches, eval_fn,
-                                    eval_batch, wcfg)
+        return _run_host(cfg, wcfg, hcfg, chan, loss_fn, init_params,
+                         sample_client_batches, eval_fn, eval_batch)
     batches = (None if cfg.datagen is not None else
                stack_batches(sample_client_batches, cfg.rounds,
                              cfg.n_devices))
-    _, logs = run_simulation_scan(cfg, loss_fn, init_params, batches,
-                                  eval_batch=eval_batch, wcfg=wcfg)
+    _, logs = _run_scan(cfg, wcfg, hcfg, chan, loss_fn, init_params, batches,
+                        eval_batch)
     return logs.to_round_logs()
 
 
-def _run_simulation_host(cfg: SimConfig, loss_fn, init_params: PyTree,
-                         sample_client_batches, eval_fn, eval_batch,
-                         wcfg: wireless.WirelessConfig) -> List[RoundLog]:
+def _run_host(cfg: SimConfig, wcfg: wireless.WirelessConfig,
+              hcfg: Optional[HFLConfig], chan, loss_fn, init_params: PyTree,
+              sample_client_batches, eval_fn, eval_batch) -> List[RoundLog]:
     """Round-by-round dispatch loop over the *same* step function the scan
     engine uses (parity baseline + host-side eval_fn support)."""
     has_eval = eval_batch is not None
-    init_carry, _, _ = _make_sim_fns(cfg, wcfg, loss_fn, has_eval)
-    step = _get_host_step(cfg, wcfg, loss_fn, has_eval)
-    key = jax.random.PRNGKey(cfg.seed)
-    k_pos, k_rounds = jax.random.split(key)
-    chan = wireless.channel_params(wcfg)
-    cparams = _resolve_cparams(cfg, init_params)
-    aparams = _resolve_aparams(cfg)
-    dist = wireless.sample_positions_jax(k_pos, chan, cfg.n_devices)
-
-    fargs = (cfg.faults,) if cfg.faults is not None else ()
-    pargs = (_resolve_pparams(cfg),) if cfg.privacy != "none" else ()
-    carry = init_carry(init_params)
-    logs: List[RoundLog] = []
+    rnd = _make_round(cfg, wcfg, loss_fn, has_eval, hcfg)
+    step = _get_host_step(cfg, wcfg, loss_fn, has_eval, hcfg)
+    k_site, k_rounds = jax.random.split(jax.random.PRNGKey(cfg.seed))
+    site = rnd.site(k_site, chan)
+    args = (chan, _resolve_cparams(cfg, init_params), _resolve_aparams(cfg),
+            *_run_args(cfg, hcfg), site, k_rounds, eval_batch)
+    carry = rnd.init_carry(init_params)
+    outs = []
     for t in range(cfg.rounds):
         bt = (None if cfg.datagen is not None
               else sample_client_batches(t, cfg.n_devices))
-        carry, (loss, clock, mask, nsched, ubits, comm_s, comp_s, dl_bits,
-                n_surv, n_drop, retx, stal, eps, dlt, mbits) = step(
-            chan, cparams, aparams, *fargs, *pargs, dist, k_rounds,
-            eval_batch, carry, (jnp.int32(t), bt))
-        mask_np = np.asarray(mask)
-        lv = float(loss)
+        carry, out = step(*args, carry, (jnp.int32(t), bt))
         if eval_fn is not None and not has_eval:
-            lv = eval_fn(carry[0].params)
-        logs.append(RoundLog(t, float(clock), lv, int(nsched), mask_np,
-                             float(ubits), float(comm_s), float(comp_s),
-                             float(dl_bits), int(n_surv), int(n_drop),
-                             float(retx), float(stal), float(eps),
-                             float(dlt), float(mbits)))
-    return logs
+            out = out._replace(loss=eval_fn(rnd.params_of(carry, site)))
+        outs.append(jax.device_get(out))
+    return _sim_logs(jax.tree.map(lambda *xs: np.stack(xs),
+                                  *outs)).to_round_logs()
 
 
 # ---------------------------------------------------------------------------
@@ -1058,13 +926,13 @@ def _dispatch_variants(engine, var_args: Tuple, shared_args: Tuple,
                        mesh) -> Tuple:
     """One compiled sweep dispatch: pads the variant axis up to a multiple
     of the mesh size (ragged grids), calls the engine, slices the padding
-    back off the outputs. Returns the stacked per-round ``outs`` tuple."""
+    back off the outputs. Returns the stacked per-round ``RoundOut``."""
     v = jax.tree.leaves(var_args[0])[0].shape[0]
     if mesh is not None:
         n_pad = (-v) % int(np.asarray(mesh.devices).size)
         var_args = tuple(_pad_variants(a, n_pad) for a in var_args)
     _, outs = engine(*var_args, *shared_args)
-    return tuple(o[:v] for o in outs)
+    return jax.tree.map(lambda o: o[:v], outs)
 
 
 def run_sweep(cfg: SimConfig, loss_fn, init_params: PyTree, batches: PyTree, *,
@@ -1222,16 +1090,6 @@ def run_sweep(cfg: SimConfig, loss_fn, init_params: PyTree, batches: PyTree, *,
                  + ((priv,) if privacies is not None else ()))
         return parts[0] if len(parts) == 1 else parts
 
-    def to_logs(outs) -> SimLogs:
-        (losses, clocks, masks, nsched, ubits, comm_s, comp_s, dl_bits,
-         n_surv, n_drop, retx, stal, eps, dlt, mbits) = jax.device_get(outs)
-        return SimLogs(loss=losses, latency_s=clocks, n_scheduled=nsched,
-                       participation=masks, uplink_bits=ubits,
-                       comm_s=comm_s, comp_s=comp_s, downlink_bits=dl_bits,
-                       n_survived=n_surv, n_dropped=n_drop,
-                       retransmissions=retx, staleness_mean=stal,
-                       epsilon=eps, delta=dlt, mask_bits=mbits)
-
     def cfg_variant(pol, comp, alg, priv) -> SimConfig:
         return dataclasses.replace(
             cfg, policy=pol, compression=comp, algorithm=alg,
@@ -1242,77 +1100,44 @@ def run_sweep(cfg: SimConfig, loss_fn, init_params: PyTree, batches: PyTree, *,
                             else cfg.privacy_params))
 
     results: Dict[Any, SimLogs] = {}
+    # mixture: one dispatch for the whole policy set, the base grid tiled
+    # policy-major and each block's policy selected by a traced one-hot;
+    # loop: one dispatch per policy
     use_mixture = (hlist is None and policy_mode == "mixture"
                    and len(policies) > 1)
-    if use_mixture:
-        # one dispatch for the whole policy set: tile the base grid
-        # policy-major and select each block's policy by a traced one-hot
-        policy_axis = tuple(policies)
-        n_base = len(grid)
-        n_pol = len(policies)
-        pol_w = jnp.repeat(jnp.eye(n_pol, dtype=jnp.float32),
-                           n_base, axis=0)
-        base_args = (_tile_variants(keys, n_pol),
-                     _tile_variants(chans, n_pol),
-                     _tile_variants(cps, n_pol),
-                     _tile_variants(aps, n_pol))
-        fps_t = _tile_variants(fps, n_pol) if faults_on else None
-        pps_t = _tile_variants(pps, n_pol) if pps is not None else None
-        for comp in comp_iter:
-            for alg in algo_iter:
-                for priv in priv_iter:
-                    cfg_v = dataclasses.replace(
-                        cfg_variant(policies[0], comp, alg, priv),
-                        policy=policies[0])
-                    with TraceAnnotation("fl.engine_lookup"):
-                        engine = _get_engine(cfg_v, wcfgs[0], loss_fn,
-                                             has_eval, vmapped=True,
-                                             policy_axis=policy_axis,
-                                             mesh=mesh)
-                    var_args = (base_args
-                                + ((fps_t,) if faults_on else ())
-                                + ((pps_t,) if priv != "none" else ())
-                                + (pol_w,))
-                    with TraceAnnotation("fl.dispatch"):
-                        outs = _dispatch_variants(engine, var_args, shared,
-                                                  mesh)
-                    with TraceAnnotation("fl.fetch_logs"):
-                        arrs = jax.device_get(outs)
-                        for p_i, pol in enumerate(policies):
-                            block = tuple(a[p_i * n_base:(p_i + 1) * n_base]
-                                          for a in arrs)
-                            results[result_key(pol, comp, alg,
-                                               priv)] = to_logs(block)
-        return results
-
-    for pol in policies:
-        for comp in comp_iter:
-            for alg in algo_iter:
-                for priv in priv_iter:
-                    cfg_v = cfg_variant(pol, comp, alg, priv)
-                    pargs = (pps,) if priv != "none" else ()
-                    with TraceAnnotation("fl.engine_lookup"):
-                        if hlist is not None:
-                            engine = _get_hfl_engine(
-                                cfg_v, hlist[0], wcfgs[0], loss_fn,
-                                has_eval, vmapped=True, mesh=mesh)
-                        else:
-                            engine = _get_engine(cfg_v, wcfgs[0], loss_fn,
-                                                 has_eval, vmapped=True,
-                                                 mesh=mesh)
-                    var_args = ((keys, chans, cps, aps)
-                                + ((bh,) if hlist is not None else ())
-                                + ((fps,) if faults_on else ())
-                                + pargs)
-                    with TraceAnnotation("fl.dispatch"):
-                        outs = _dispatch_variants(engine, var_args, shared,
-                                                  mesh)
-                    with TraceAnnotation("fl.fetch_logs"):
-                        results[result_key(pol, comp, alg,
-                                           priv)] = to_logs(outs)
+    n_base = len(grid)
+    for group in ([tuple(policies)] if use_mixture
+                  else [(p,) for p in policies]):
+        n_pol = len(group)
+        policy_axis = group if n_pol > 1 else None
+        base_args = ((keys, chans, cps, aps)
+                     + ((bh,) if hlist is not None else ())
+                     + ((fps,) if faults_on else ()))
+        pargs = (pps,)
+        if policy_axis is not None:
+            base_args = tuple(_tile_variants(a, n_pol) for a in base_args)
+            pargs = (_tile_variants(pps, n_pol),) if pps is not None else ()
+        mix_args = ((jnp.repeat(jnp.eye(n_pol, dtype=jnp.float32), n_base,
+                                axis=0),) if policy_axis is not None else ())
+        for comp, alg, priv in itertools.product(comp_iter, algo_iter,
+                                                 priv_iter):
+            cfg_v = cfg_variant(group[0], comp, alg, priv)
+            with TraceAnnotation("fl.engine_lookup"):
+                engine = _get_engine(
+                    cfg_v, wcfgs[0], loss_fn, has_eval,
+                    hcfg=hlist[0] if hlist is not None else None,
+                    vmapped=True, policy_axis=policy_axis, mesh=mesh)
+            var_args = (base_args + (pargs if priv != "none" else ())
+                        + mix_args)
+            with TraceAnnotation("fl.dispatch"):
+                outs = _dispatch_variants(engine, var_args, shared, mesh)
+            with TraceAnnotation("fl.fetch_logs"):
+                arrs = jax.device_get(outs)
+                for p_i, pol in enumerate(group):
+                    results[result_key(pol, comp, alg, priv)] = _sim_logs(
+                        jax.tree.map(lambda a: a[p_i * n_base:
+                                                 (p_i + 1) * n_base], arrs))
     return results
-
-
 
 
 # ---------------------------------------------------------------------------
@@ -1354,11 +1179,11 @@ def _check_hfl_config(cfg: SimConfig) -> None:
 
 
 def _make_hfl_fns(cfg: SimConfig, hcfg: HFLConfig,
-                  wcfg: wireless.WirelessConfig, loss_fn, has_eval: bool):
-    """Shared wireless-aware HFL round logic for both engines. Returns
-    ``(init_carry, make_step, engine)`` exactly like :func:`_make_sim_fns`
-    (the host loop jits the same step the scanned engine scans, and the
-    engine signature matches the flat one so ``run_sweep`` can vmap it).
+                  wcfg: wireless.WirelessConfig, loss_fn,
+                  has_eval: bool) -> _Round:
+    """The wireless-aware HFL round, in the same frame as
+    :func:`_make_sim_fns` (the host loop jits the same step the scanned
+    engine scans, and ``run_sweep`` vmaps the engine like the flat one).
 
     One round (Alg. 9 + §III wireless):
 
@@ -1417,6 +1242,12 @@ def _make_hfl_fns(cfg: SimConfig, hcfg: HFLConfig,
     masks_on = priv_on and priv.uses_masks
     field_on = priv_on and priv.uses_field
 
+    def site(k_geo, chan):
+        return hfl_geometry_jax(k_geo, hcfg, n)
+
+    def params_of(carry, geo):
+        return inter_cluster_average(carry[0], geo[3])
+
     def init_carry(init_params):
         d = fl_server.flat_dim(init_params)
         cm = jax.tree.map(
@@ -1427,104 +1258,53 @@ def _make_hfl_fns(cfg: SimConfig, hcfg: HFLConfig,
         ctrl = jnp.zeros((n, d), jnp.float32) if algo.uses_ctrl else None
         cc = (jnp.zeros((n_clusters, d), jnp.float32) if algo.uses_ctrl
               else None)
-        carry = (cm, gm, ef, ctrl, cc, jnp.float32(0.0),
-                 jnp.zeros(n, jnp.float32), jnp.ones(n, jnp.float32),
-                 jnp.zeros(n, jnp.float32))
-        if faults_on:
-            carry = carry + (jnp.ones(n, dtype=bool),
-                             jnp.zeros((n, 2), jnp.float32),
-                             jnp.zeros(n, jnp.float32))
-        if dp_on:
-            carry = carry + (jnp.zeros(len(privacy_lib.ALPHAS),
-                                       jnp.float32),)
-        return carry
+        return cm, gm, ef, ctrl, cc, link.LinkState.init(n, faults_on, dp_on)
 
     def make_step(chan: wireless.ChannelParams, cparams: CompressionParams,
-                  aparams: AlgoParams, bh_rate, fparams, pparams, geo,
+                  aparams: AlgoParams, extra, fparams, pparams, pol_w, geo,
                   k_rounds: jax.Array, eval_batch):
+        (bh_rate,) = extra
         cluster_ids, dist, member, cluster_sizes = geo
         chan_dev = wireless.gather_channel_params(chan, cluster_ids)
         member_f = member.astype(jnp.float32)                       # (L, N)
         w_cluster = cluster_sizes / jnp.maximum(jnp.sum(cluster_sizes), 1.0)
 
-        def rate_of(snr_v):
-            # each device shares its own cell's uplink budget
-            if per_cluster_k:
-                ks_dev = jnp.asarray(ks, jnp.float32)[cluster_ids]
-                return wireless.shannon_rate_jax(
-                    snr_v, chan_dev.bandwidth_hz / ks_dev)
-            return wireless.shannon_rate_jax(
-                snr_v, chan_dev.bandwidth_hz / cfg.n_scheduled)
+        # each device shares its own cell's uplink budget, and agrees masks
+        # with its *cluster* peers only (the overhead varies with each
+        # device's cell population)
+        k_dev = (jnp.asarray(ks, jnp.float32)[cluster_ids] if per_cluster_k
+                 else cfg.n_scheduled)
+        lk = link.Link(
+            cfg, algo.uplink_factor, priv, chan_dev, dist,
+            lambda snr: wireless.shannon_rate_jax(
+                snr, chan_dev.bandwidth_hz / k_dev),
+            jnp.maximum(cluster_sizes[cluster_ids] - 1.0, 0.0), fparams,
+            pparams)
 
         def step(carry, xs):
-            if dp_on:
-                carry, rdp = carry[:-1], carry[-1]
-            if faults_on:
-                (cm, gm, ef, ctrl, cc, clock, ages, norms, avg_snr,
-                 avail, fad, stal) = carry
-            else:
-                cm, gm, ef, ctrl, cc, clock, ages, norms, avg_snr = carry
+            cm, gm, ef, ctrl, cc, ls = carry
             t, batches = xs
             kt = jax.random.fold_in(k_rounds, t)
             kf, kc, kp, kn, kz = jax.random.split(kt, 5)
             if priv_on:
-                # fold-tagged so the legacy streams above are untouched —
-                # privacy="none" is bitwise the old HFL engine
+                # fold-tagged so the five streams above are untouched —
+                # privacy="none" draws what an engine without it draws
                 k_priv = jax.random.fold_in(kt, privacy_lib.PRIVACY_FOLD)
-
-            # --- channel draw + intra-cluster uplink pricing -------------
-            if faults_on:
-                fad, fading = faults_lib.gauss_markov_fading(
-                    fparams, kt, fad, t)
-            else:
-                fading = wireless.sample_fading_jax(kf, n)
-            snr_lin = wireless.snr_jax(dist, fading, chan_dev)
-            rates = rate_of(snr_lin)
-            comp_lat = cfg.comp_latency_s * jax.random.exponential(kc, (n,))
-            if faults_on:
-                comp_lat = comp_lat * faults_lib.straggler_multiplier(
-                    fparams, kt, n)
             d_model = fl_server.flat_dim(gm)
-            payload_scale = cfg.model_bits / (32.0 * d_model)
-            msg_bits = message_bits_jax(cfg.compression, cparams,
-                                        cfg.model_bits, d_model)
-            if field_on:
-                # a masked message is incompressible: dense field_bits per
-                # coordinate replaces the compressor's rate on the wire
-                msg_bits = payload_scale * privacy_lib.uplink_bits_jax(
-                    cfg.privacy, pparams, d_model, 0.0)
-            bits_dev = msg_bits * algo.uplink_factor
-            mask_over = jnp.float32(0.0)
-            if masks_on:
-                # pairwise key agreement with *cluster* peers only — the
-                # per-device overhead varies with its cell's population, so
-                # bits_dev becomes a (N,) vector here
-                mask_over = privacy_lib.mask_bits_jax(
-                    cfg.privacy,
-                    jnp.maximum(cluster_sizes[cluster_ids] - 1.0, 0.0))
-                bits_dev = bits_dev + mask_over
-
-            def bill(w_):
-                # bits_dev is per-device when mask overhead is on; the
-                # faults/legacy scalar form is kept bitwise otherwise
-                return (jnp.sum(bits_dev * w_) if masks_on
-                        else bits_dev * jnp.sum(w_))
-
-            comm_lat = wireless.comm_latency_jax(bits_dev, rates)
-            avg_snr = jnp.where(t == 0, snr_lin,
-                                0.9 * avg_snr + 0.1 * snr_lin)
+            ls, up = lk.uplink(ls, kt, kf, kc, t, d_model, cparams)
 
             # --- per-cluster scheduling (registry policy) ----------------
             if faults_on:
                 # churned-off devices disappear from their cluster's view
-                avail = faults_lib.churn_step(fparams, kt, avail)
-                member_eff = member & avail[None, :]
+                ls = ls._replace(avail=faults_lib.churn_step(
+                    fparams, kt, ls.avail))
+                member_eff = member & ls.avail[None, :]
             else:
                 member_eff = member
             rstate = scheduling.RoundState(
-                t=t, key=kp, snr_lin=snr_lin, avg_snr=avg_snr, rates=rates,
-                comm_lat=comm_lat, comp_lat=comp_lat, ages=ages,
-                update_norms=norms)
+                t=t, key=kp, snr_lin=up.snr_lin, avg_snr=ls.avg_snr,
+                rates=up.rates, comm_lat=up.comm_lat, comp_lat=up.comp_lat,
+                ages=ls.ages, update_norms=ls.norms)
             keys_l = jax.random.split(kp, n_clusters)
             k_sched = ks[0]
 
@@ -1589,29 +1369,8 @@ def _make_hfl_fns(cfg: SimConfig, hcfg: HFLConfig,
                 else:
                     masks_l = jax.vmap(sched_one)(member_eff, keys_l)
             mask = jnp.any(masks_l, axis=0)
-            stal_pre = stal if faults_on else None
-            ages = scheduling.update_ages_jax(ages, mask)
-            mask_f = mask.astype(jnp.float32)
-
-            # --- mid-round dropout + decode failure + retransmissions ----
-            if faults_on:
-                dropped = faults_lib.dropout_draw(fparams, kt, n) & mask
-                ok = snr_lin >= fparams.snr_min
-                comm_eff = comm_lat
-                n_retx = jnp.zeros(n, jnp.float32)
-                for r in range(1, cfg.max_retries + 1):
-                    fad_r = faults_lib.retry_fading(kt, r, n)
-                    snr_r = wireless.snr_jax(dist, fad_r, chan_dev)
-                    lat_r = wireless.comm_latency_jax(bits_dev,
-                                                      rate_of(snr_r))
-                    need = ~ok
-                    comm_eff = comm_eff + jnp.where(need, lat_r, 0.0)
-                    n_retx = n_retx + need.astype(jnp.float32)
-                    ok = ok | (snr_r >= fparams.snr_min)
-                survived = mask & ~dropped & ok
-                part_f = survived.astype(jnp.float32)
-            else:
-                part_f = mask_f
+            ls = ls._replace(ages=scheduling.update_ages_jax(ls.ages, mask))
+            fate, part_f = lk.retransmit(kt, up, mask)
 
             # --- local updates from each device's cluster model ----------
             client_params = broadcast_to_clients(cm, cluster_ids)
@@ -1645,7 +1404,7 @@ def _make_hfl_fns(cfg: SimConfig, hcfg: HFLConfig,
                 if faults_on:
                     # a dropped/undecoded client's residual carries forward
                     # untouched — its payload never reached the SBS
-                    ef = jnp.where(survived[:, None], flat - wire, ef)
+                    ef = jnp.where(fate.survived[:, None], flat - wire, ef)
                 else:
                     ef = flat - wire
                 flat = wire
@@ -1660,22 +1419,10 @@ def _make_hfl_fns(cfg: SimConfig, hcfg: HFLConfig,
                     bits = jnp.broadcast_to(
                         pparams.field_bits * jnp.float32(d_model),
                         bits.shape)
-                ubits_intra = payload_scale * jnp.sum(bits * part_f)
-                if masks_on:
-                    # key agreement for every *scheduled* member (it
-                    # precedes the transmission that may then fail)
-                    ubits_intra = ubits_intra + jnp.sum(mask_over * mask_f)
-                if faults_on:
-                    ubits_intra = ubits_intra + bill(
-                        jnp.where(mask & ~dropped,
-                                  n_retx + (~ok).astype(jnp.float32), 0.0))
+                ubits_intra = lk.bill(up, mask, fate, jnp.sum(bits * part_f))
             else:
                 k_bh = kz
-                if faults_on:
-                    ubits_intra = bill(jnp.where(
-                        mask & ~dropped, 1.0 + n_retx, 0.0))
-                else:
-                    ubits_intra = bill(mask_f)
+                ubits_intra = lk.bill(up, mask, fate)
 
             # --- SBS aggregation: masked per-cluster delta mean ----------
             # (fault mode aggregates only the *survivors*; a cluster whose
@@ -1765,7 +1512,7 @@ def _make_hfl_fns(cfg: SimConfig, hcfg: HFLConfig,
                     bh_wire, bh_bits = jax.vmap(
                         compress_fn, in_axes=(None, 0, 0))(
                             cparams, keys_bh, bh_deltas)
-                    bh_bits_sbs = payload_scale * bh_bits        # (L,)
+                    bh_bits_sbs = up.payload_scale * bh_bits    # (L,)
                 else:
                     bh_wire = bh_deltas
                     bh_bits_sbs = jnp.full((n_clusters,), cfg.model_bits,
@@ -1797,169 +1544,52 @@ def _make_hfl_fns(cfg: SimConfig, hcfg: HFLConfig,
             # cluster model to the members opening the round; on sync
             # rounds the MBS additionally pushes the fresh global model
             # back over every SBS's fronthaul link (parallel, equal cost).
+            # The round time is the slowest scheduled device's plus the
+            # backhaul's.
             mb = jnp.float32(cfg.model_bits)
-            dl_rate = wireless.shannon_rate_jax(
-                wireless.downlink_snr_jax(
-                    dist, faults_lib.downlink_fading(kt, n), chan_dev),
-                chan_dev.bandwidth_hz)
-            dl_lat = wireless.comm_latency_jax(mb, dl_rate)
-            any_sched = jnp.any(mask)
-            dl_s = jnp.max(jnp.where(mask, dl_lat, 0.0))
             sync_f = sync.astype(jnp.float32)
             bh_time = bh_time + sync_f * (mb / bh_rate)
-            dl_bits_out = (jnp.where(any_sched, mb * n_clusters, 0.0)
-                           + sync_f * mb * n_clusters)
 
-            # --- wall clock: slowest scheduled device + backhaul ---------
-            if faults_on:
-                comm_c = jnp.where(dropped, 0.0, comm_eff)
-                comp_c = jnp.where(dropped, 0.0, comp_lat)
-            else:
-                comm_c, comp_c = comm_lat, comp_lat
-            total = comm_c + comp_c
-            slowest = jnp.argmax(jnp.where(mask, total, -jnp.inf))
-            comm_s = jnp.where(any_sched, comm_c[slowest], 0.0)
-            comp_s = jnp.where(any_sched, comp_c[slowest], 0.0)
-            clock = clock + dl_s + comm_s + comp_s + bh_time
+            def z_eff(_):
+                # clusters compose in *parallel* (disjoint populations), so
+                # the round's guarantee is the worst cell's: local field
+                # noise aggregates to sigma * sqrt(m) in the smallest
+                # non-empty cluster; central dp adds sigma per cluster
+                if not priv.dp_local:
+                    return pparams.sigma
+                m_min = jnp.min(jnp.where(cnt > 0.0, cnt, jnp.inf))
+                return pparams.sigma * jnp.sqrt(
+                    jnp.where(jnp.isfinite(m_min), m_min, 1.0))
 
-            if faults_on:
-                stal_log = jnp.mean(stal_pre)
-                stal = jnp.where(survived, 0.0, stal + 1.0)
-                retx_log = jnp.sum(jnp.where(mask & ~dropped, n_retx, 0.0))
-                n_surv = jnp.sum(survived).astype(jnp.int32)
-                n_drop = jnp.sum(mask & ~survived).astype(jnp.int32)
-            else:
-                stal_log = jnp.float32(0.0)
-                retx_log = jnp.float32(0.0)
-                n_surv = jnp.sum(mask).astype(jnp.int32)
-                n_drop = jnp.int32(0)
-
-            # --- (epsilon, delta) accounting: clusters compose in
-            # *parallel* (disjoint populations), so the round's guarantee
-            # is the worst cell's. Local field noise aggregates to an
-            # effective multiplier sigma * sqrt(m) in the smallest
-            # non-empty cluster; central dp adds sigma per cluster.
-            if dp_on:
-                q_frac = jnp.sum(part_f) / n
-                if priv.dp_local:
-                    cnt_pos = jnp.where(cnt > 0.0, cnt, jnp.inf)
-                    m_min = jnp.min(cnt_pos)
-                    z_eff = pparams.sigma * jnp.sqrt(
-                        jnp.where(jnp.isfinite(m_min), m_min, 1.0))
-                else:
-                    z_eff = pparams.sigma
-                rdp = rdp + privacy_lib.rdp_increment(q_frac, z_eff)
-                eps = privacy_lib.epsilon_of(rdp)
-                delta_out = jnp.float32(privacy_lib.DELTA)
-            else:
-                eps = jnp.float32(jnp.inf)
-                delta_out = jnp.float32(1.0)
-            mask_bits_out = jnp.sum(mask_over * mask_f)
-
+            ls, out = lk.close(ls, kt, up, mask, part_f, fate, ubits, mb,
+                               z_eff, bh_time)
+            out = out._replace(downlink_bits=out.downlink_bits * n_clusters
+                               + sync_f * mb * n_clusters)
             loss = jnp.mean(losses)
             if has_eval:
                 loss = loss_fn(inter_cluster_average(cm, cluster_sizes),
                                eval_batch)[0]
-            norms = 0.9 * norms + 0.1 * jax.random.exponential(kn, (n,))
-            new_carry = (cm, gm, ef, ctrl, cc, clock, ages, norms, avg_snr)
-            if faults_on:
-                new_carry = new_carry + (avail, fad, stal)
-            if dp_on:
-                new_carry = new_carry + (rdp,)
-            return new_carry, (
-                loss, clock, mask, jnp.sum(mask), ubits, comm_s, comp_s,
-                dl_bits_out, n_surv, n_drop, retx_log, stal_log, eps,
-                delta_out, mask_bits_out)
+            ls, out = lk.finish(ls, kn, out, loss)
+            return (cm, gm, ef, ctrl, cc, ls), out
 
         return step
 
-    def _scan(key, chan, cparams, aparams, bh_rate, fparams, pparams,
+    def _scan(key, chan, cparams, aparams, extra, fparams, pparams, pol_w,
               init_params, batches_all, eval_batch):
-        ENGINE_STATS["traces"] += 1  # python side effect: runs at trace only
         k_geo, k_rounds = jax.random.split(key)
-        geo = hfl_geometry_jax(k_geo, hcfg, n)
-        step = make_step(chan, cparams, aparams, bh_rate, fparams, pparams,
-                         geo, k_rounds, eval_batch)
+        geo = site(k_geo, chan)
+        step = make_step(chan, cparams, aparams, extra, fparams, pparams,
+                         pol_w, geo, k_rounds, eval_batch)
         ts = jnp.arange(cfg.rounds, dtype=jnp.int32)
         carry, outs = lax.scan(
             step, compat.vary_like(init_carry(init_params), key),
             (ts, batches_all))
-        cm = carry[0]
-        final = jax.tree.map(
-            lambda p0, f: f.astype(p0.dtype), init_params,
-            inter_cluster_average(cm, geo[3]))
+        final = jax.tree.map(lambda p0, f: f.astype(p0.dtype), init_params,
+                             params_of(carry, geo))
         return final, outs
 
-    # optional traced axes in the same fixed order as the flat engine:
-    # fparams, then pparams (the three shared trailing args close the list)
-    def engine(key, chan, cparams, aparams, bh_rate, *rest):
-        rest = list(rest)
-        fparams = rest.pop(0) if faults_on else None
-        pparams = rest.pop(0) if priv_on else None
-        init_params, batches_all, eval_batch = rest
-        return _scan(key, chan, cparams, aparams, bh_rate, fparams, pparams,
-                     init_params, batches_all, eval_batch)
-
-    return init_carry, make_step, engine
-
-
-def _hfl_engine_key(cfg: SimConfig, hcfg: HFLConfig,
-                    wcfg: wireless.WirelessConfig, loss_fn, has_eval: bool,
-                    tag: str) -> Tuple:
-    # HFLConfig is a frozen (hashable) dataclass; the key holds its
-    # static_key() — the traced backhaul_rate_bps is zeroed out, so a
-    # backhaul-rate grid shares one compiled engine.
-    return _engine_key(cfg, wcfg, loss_fn, has_eval, tag) + (
-        hcfg.static_key(),)
-
-
-def _get_hfl_engine(cfg: SimConfig, hcfg: HFLConfig,
-                    wcfg: wireless.WirelessConfig, loss_fn, has_eval: bool,
-                    *, vmapped: bool = False, mesh=None) -> Callable:
-    def make():
-        _, _, engine = _make_hfl_fns(cfg, hcfg, wcfg, loss_fn, has_eval)
-        n_var = 5 + (cfg.faults is not None) + (cfg.privacy != "none")
-        if vmapped:
-            vengine = jax.vmap(engine,
-                               in_axes=(0,) * n_var + (None,) * 3)
-            if mesh is not None:
-                vengine = _shard_variants(vengine, mesh, n_var)
-            return jax.jit(vengine)
-        # no donation: the broadcast to (L, ...) cluster models copies the
-        # initial params anyway, so there is no aliasable output buffer
-        return jax.jit(engine)
-
-    return _cached(_ENGINE_CACHE,
-                   _hfl_engine_key(cfg, hcfg, wcfg, loss_fn, has_eval,
-                                   "hfl-sweep" if vmapped else "hfl-single")
-                   + _mesh_key(mesh), make)
-
-
-def _get_hfl_host_step(cfg: SimConfig, hcfg: HFLConfig,
-                       wcfg: wireless.WirelessConfig, loss_fn,
-                       has_eval: bool) -> Callable:
-    """Jitted per-round HFL step with the run-specific values (channel
-    params, geometry, round key, eval batch) as *arguments* — shared across
-    runs of the same static config, exactly like :func:`_get_host_step`."""
-    def make():
-        _, make_step, _ = _make_hfl_fns(cfg, hcfg, wcfg, loss_fn, has_eval)
-        faults_on = cfg.faults is not None
-        priv_on = cfg.privacy != "none"
-
-        # optional args in the engines' fixed order: fparams, then pparams
-        def host_step(chan, cparams, aparams, bh_rate, *rest):
-            rest = list(rest)
-            fparams = rest.pop(0) if faults_on else None
-            pparams = rest.pop(0) if priv_on else None
-            geo, k_rounds, eval_batch, carry, xs = rest
-            return make_step(chan, cparams, aparams, bh_rate, fparams,
-                             pparams, geo, k_rounds, eval_batch)(carry, xs)
-
-        return jax.jit(host_step)
-
-    return _cached(_ENGINE_CACHE,
-                   _hfl_engine_key(cfg, hcfg, wcfg, loss_fn, has_eval,
-                                   "hfl-host-step"), make)
+    return _Round(cfg, init_carry, make_step, _scan, site, params_of,
+                  n_extra=1)
 
 
 def _resolve_hfl_channel(cfg: SimConfig, hcfg: HFLConfig, wcfg, cluster_wcfgs
@@ -2020,76 +1650,7 @@ def run_hfl(cfg: SimConfig, hcfg: HFLConfig, loss_fn, init_params: PyTree,
     (heterogeneous cells; each entry also sets that cell's uplink
     bandwidth split).
     """
-    if engine not in (None, "scan", "host"):
-        raise ValueError(f"unknown engine {engine!r}; use 'scan' or 'host'")
     _check_hfl_config(cfg)
-    if cfg.rounds == 0:
-        return []
     wcfg_stat, chan = _resolve_hfl_channel(cfg, hcfg, wcfg, cluster_wcfgs)
-    eval_batch = getattr(eval_fn, "eval_batch", None) if eval_fn else None
-    opaque_eval = eval_fn is not None and eval_batch is None
-    if engine == "scan" and opaque_eval:
-        raise ValueError(
-            "engine='scan' needs an in-program eval: attach eval_fn."
-            "eval_batch (logged loss becomes loss_fn(params, eval_batch)) "
-            "or drop engine= to let the host loop serve the opaque eval_fn")
-    if engine == "host" or opaque_eval:
-        return _run_hfl_host(cfg, hcfg, loss_fn, init_params,
-                             sample_client_batches, eval_fn, eval_batch,
-                             chan, wcfg_stat)
-    batches = stack_batches(sample_client_batches, cfg.rounds, cfg.n_devices)
-    cparams = _resolve_cparams(cfg, init_params)
-    aparams = _resolve_aparams(cfg)
-    eng = _get_hfl_engine(cfg, hcfg, wcfg_stat, loss_fn,
-                          eval_batch is not None)
-    key = jax.random.PRNGKey(cfg.seed)
-    fargs = (cfg.faults,) if cfg.faults is not None else ()
-    pargs = (_resolve_pparams(cfg),) if cfg.privacy != "none" else ()
-    _, outs = eng(key, chan, cparams, aparams,
-                  jnp.float32(hcfg.backhaul_rate_bps), *fargs, *pargs,
-                  init_params, batches, eval_batch)
-    (losses, clocks, masks, nsched, ubits, comm_s, comp_s, dl_bits,
-     n_surv, n_drop, retx, stal, eps, dlt, mbits) = jax.device_get(outs)
-    return SimLogs(loss=losses, latency_s=clocks, n_scheduled=nsched,
-                   participation=masks, uplink_bits=ubits, comm_s=comm_s,
-                   comp_s=comp_s, downlink_bits=dl_bits, n_survived=n_surv,
-                   n_dropped=n_drop, retransmissions=retx,
-                   staleness_mean=stal, epsilon=eps, delta=dlt,
-                   mask_bits=mbits).to_round_logs()
-
-
-def _run_hfl_host(cfg: SimConfig, hcfg: HFLConfig, loss_fn,
-                  init_params: PyTree, sample_client_batches, eval_fn,
-                  eval_batch, chan: wireless.ChannelParams,
-                  wcfg_stat: wireless.WirelessConfig) -> List[RoundLog]:
-    """Per-round HFL dispatch loop over the *same* round step the scanned
-    engine uses (host-side eval_fn support; parity baseline)."""
-    has_eval = eval_batch is not None
-    init_carry, _, _ = _make_hfl_fns(cfg, hcfg, wcfg_stat, loss_fn, has_eval)
-    step = _get_hfl_host_step(cfg, hcfg, wcfg_stat, loss_fn, has_eval)
-    key = jax.random.PRNGKey(cfg.seed)
-    k_geo, k_rounds = jax.random.split(key)
-    geo = hfl_geometry_jax(k_geo, hcfg, cfg.n_devices)
-    cparams = _resolve_cparams(cfg, init_params)
-    aparams = _resolve_aparams(cfg)
-
-    fargs = (cfg.faults,) if cfg.faults is not None else ()
-    pargs = (_resolve_pparams(cfg),) if cfg.privacy != "none" else ()
-    carry = init_carry(init_params)
-    logs: List[RoundLog] = []
-    for t in range(cfg.rounds):
-        bt = sample_client_batches(t, cfg.n_devices)
-        carry, (loss, clock, mask, nsched, ubits, comm_s, comp_s, dl_bits,
-                n_surv, n_drop, retx, stal, eps, dlt, mbits) = step(
-            chan, cparams, aparams, jnp.float32(hcfg.backhaul_rate_bps),
-            *fargs, *pargs, geo, k_rounds, eval_batch, carry,
-            (jnp.int32(t), bt))
-        lv = float(loss)
-        if eval_fn is not None and not has_eval:
-            lv = eval_fn(inter_cluster_average(carry[0], geo[3]))
-        logs.append(RoundLog(t, float(clock), lv, int(nsched),
-                             np.asarray(mask), float(ubits), float(comm_s),
-                             float(comp_s), float(dl_bits), int(n_surv),
-                             int(n_drop), float(retx), float(stal),
-                             float(eps), float(dlt), float(mbits)))
-    return logs
+    return _run(cfg, wcfg_stat, hcfg, chan, loss_fn, init_params,
+                sample_client_batches, eval_fn, engine)

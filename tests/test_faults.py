@@ -105,7 +105,7 @@ def test_all_dropped_round_leaves_state_bitwise_unchanged(algorithm,
     cfg = _cfg(algorithm=algorithm, compression=compression,
                faults=fault_params(drop_prob=1.0), max_retries=0)
     wcfg = wireless.WirelessConfig(n_devices=cfg.n_devices)
-    init_carry, _, _ = rt._make_sim_fns(cfg, wcfg, loss_fn, False)
+    init_carry = rt._make_sim_fns(cfg, wcfg, loss_fn, False).init_carry
     step = rt._get_host_step(cfg, wcfg, loss_fn, False)
     key = jax.random.PRNGKey(cfg.seed)
     k_pos, k_rounds = jax.random.split(key)
@@ -116,8 +116,8 @@ def test_all_dropped_round_leaves_state_bitwise_unchanged(algorithm,
     batch = make_batches(0, cfg.n_devices)
     carry1, outs = step(chan, cparams, rt._resolve_aparams(cfg), cfg.faults,
                         dist, k_rounds, None, carry0, (jnp.int32(0), batch))
-    assert int(outs[8]) == 0  # n_survived
-    s0, s1 = carry0[0], carry1[0]
+    assert int(outs.n_survived) == 0
+    (s0, _), (s1, _) = carry0, carry1
     for a, b in zip(jax.tree.leaves(s0.params), jax.tree.leaves(s1.params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     if s0.client_error is not None:

@@ -343,7 +343,7 @@ def test_chunking_bounds_compiled_temp_memory():
                            algo_params=AP01, compression="topk",
                            chunk_size=chunk, datagen=dg)
         wcfg = rt.wireless.WirelessConfig(n_devices=cfg.n_devices)
-        _, _, engine = rt._make_sim_fns(cfg, wcfg, loss_fn, False)
+        engine = rt._make_sim_fns(cfg, wcfg, loss_fn, False).engine
         lowered = jax.jit(engine).lower(
             jax.random.PRNGKey(0), rt.wireless.channel_params(wcfg),
             rt._resolve_cparams(cfg, params), rt._resolve_aparams(cfg),

@@ -184,7 +184,7 @@ def test_all_dropped_round_is_noop_with_secagg():
     cfg = _cfg(privacy="secagg", privacy_params=PP,
                faults=fault_params(drop_prob=1.0), max_retries=0)
     wcfg = wireless.WirelessConfig(n_devices=cfg.n_devices)
-    init_carry, _, _ = rt._make_sim_fns(cfg, wcfg, loss_fn, False)
+    init_carry = rt._make_sim_fns(cfg, wcfg, loss_fn, False).init_carry
     step = rt._get_host_step(cfg, wcfg, loss_fn, False)
     key = jax.random.PRNGKey(cfg.seed)
     k_pos, k_rounds = jax.random.split(key)
@@ -195,9 +195,10 @@ def test_all_dropped_round_is_noop_with_secagg():
     carry1, outs = step(chan, rt._resolve_cparams(cfg, params0),
                         rt._resolve_aparams(cfg), cfg.faults, PP, dist,
                         k_rounds, None, carry0, (jnp.int32(0), batch))
-    assert int(outs[8]) == 0  # n_survived
-    for p0, p1 in zip(jax.tree.leaves(carry0[0].params),
-                      jax.tree.leaves(carry1[0].params)):
+    assert int(outs.n_survived) == 0
+    (s0, _), (s1, _) = carry0, carry1
+    for p0, p1 in zip(jax.tree.leaves(s0.params),
+                      jax.tree.leaves(s1.params)):
         np.testing.assert_array_equal(np.asarray(p0), np.asarray(p1))
 
 

@@ -121,7 +121,7 @@ def test_fleet_engine_step_fits_v5e(one_chip, monkeypatch):
                        chunk_size=4096, datagen=make_linear_datagen(w_star),
                        compression="topk")
     wcfg = rt.wireless.WirelessConfig(n_devices=cfg.n_devices)
-    _, _, engine = rt._make_sim_fns(cfg, wcfg, loss_fn, False)
+    engine = rt._make_sim_fns(cfg, wcfg, loss_fn, False).engine
     args = (jax.random.PRNGKey(cfg.seed), rt.wireless.channel_params(wcfg),
             rt._resolve_cparams(cfg, params), rt._resolve_aparams(cfg),
             params)
@@ -149,7 +149,7 @@ def test_chunked_ef_state_is_written_in_place_per_block(one_chip,
                        chunk_size=chunk, datagen=make_linear_datagen(w_star),
                        compression="topk")
     wcfg = rt.wireless.WirelessConfig(n_devices=cfg.n_devices)
-    _, _, engine = rt._make_sim_fns(cfg, wcfg, loss_fn, False)
+    engine = rt._make_sim_fns(cfg, wcfg, loss_fn, False).engine
     args = (jax.random.PRNGKey(cfg.seed), rt.wireless.channel_params(wcfg),
             rt._resolve_cparams(cfg, params), rt._resolve_aparams(cfg),
             params)
