@@ -401,13 +401,20 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
                         keep.reshape((-1,) + (1,) * (nw.ndim - 1)), nw, old),
                     new_ef_b, ef_b)
 
-        if put_ef is not None and new_ef_b is not None:
+        if put_ef is not None:
             # the write-back goes before the block's sums, held there by a
             # barrier: XLA would otherwise schedule it after them and keep
             # the corrected (chunk, D) message alive through the sums (on
-            # the TPU at D = 1.24e8, 0.2 GiB more scratch)
-            flat, new_ef_b = lax.optimization_barrier((flat,
-                                                       put_ef(new_ef_b)))
+            # the TPU at D = 1.24e8, 0.2 GiB more scratch). The barrier
+            # also ends the messages' concatenate before the sums: at
+            # blocks of 1, XLA would fold the stacked partials' unit axis
+            # into it, and the TPU compiler then relays each leaf by an
+            # unrolled reshape instead of a copy (at D = 2.67e8, 0.18 GiB
+            # more program)
+            if new_ef_b is not None:
+                new_ef_b = put_ef(new_ef_b)
+            flat, ctrl_wire, new_ef_b = lax.optimization_barrier(
+                (flat, ctrl_wire, new_ef_b))
 
         w = valid if part_b is None else part_b
         if priv is not None:
@@ -487,10 +494,16 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
             psums, new_ef_all, new_ctrl_b = client_block(
                 ids, batches_b, part_b, sw_b, rows(ef_all, b),
                 rows(ctrl_all, b), put_ef=lambda e: put_rows(ef_all, e, b))
+            psums = {k: v[None] if v.ndim else v for k, v in psums.items()}
             return (new_ef_all, put_rows(ctrl_all, new_ctrl_b, b)), psums
 
-        # the loop stacks the block partials for the fold below: work
-        # under no stage of its own is aggregation's
+        # the scan stacks each block's D-wide partials as a (1, D) row, so
+        # the stack is (m, 1, D): the block index is a leading, untiled
+        # axis, each block writes only its own slab and the fold below
+        # reads whole slabs. Stacked as (m, D), every tile of the TPU's
+        # tiled layout holds a row of each block, so each block's write
+        # would rewrite the whole stack and the fold slice through it.
+        # Work under no stage of its own is aggregation's.
         with jax.named_scope("fl.aggregate"):
             (client_error, new_ctrl), psums_m = lax.scan(
                 scan_block, (_with_rows(ef, (m, chunk)),
@@ -501,8 +514,8 @@ def fl_round(state: FLState, stacked_batches, loss_fn, *,
         # block partials are aligned subtrees of the full canonical tree, so
         # folding them canonically reproduces the unchunked sum bit-for-bit
         with jax.named_scope("fl.aggregate"):
-            totals = {k: chunking.canonical_sum(v)
-                      for k, v in psums_m.items()}
+            totals = {k: chunking.canonical_sum(v) for k, v in psums_m.items()}
+            totals = {k: v[0] if v.ndim else v for k, v in totals.items()}
     else:
         _state_rows(ef, "client_error", ((n,),), "the client count")
         _state_rows(state.ctrl, "ctrl", ((n,),), "the client count")

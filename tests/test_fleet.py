@@ -39,6 +39,16 @@ def _problem():
     return params, loss_fn, make_batches, w_star
 
 
+def _elementwise_loss(params, batch):
+    """The linear problem's loss with the prediction as a product and a
+    sum, no dot: XLA's CPU backend lowers a batch-1 vmapped dot (a pass
+    of blocks of one client) in another summation order than the batched
+    dot, so per-client updates would differ in the last ulp before any
+    sum of the round (ROADMAP C1)."""
+    pred = jnp.sum(batch["x"] * params["w"], axis=-1)
+    return jnp.mean((pred - batch["y"]) ** 2), {}
+
+
 # ---------------------------------------------------------------------------
 # canonical reduction tree
 # ---------------------------------------------------------------------------
@@ -55,6 +65,9 @@ def test_canonical_sum_chunk_invariance():
         partials = jax.vmap(chunking.canonical_sum)(blocks)
         got = np.asarray(chunking.canonical_sum(partials))
         np.testing.assert_array_equal(got, full)
+        # stacked as the chunked pass stacks them, (m, 1, ...)
+        got = np.asarray(chunking.canonical_sum(partials[:, None])[0])
+        np.testing.assert_array_equal(got, full)
 
 
 def test_canonical_sum_weighted_matches_masked():
@@ -70,11 +83,15 @@ def test_canonical_sum_weighted_matches_masked():
 # ---------------------------------------------------------------------------
 def _round_outputs(name, chunk, *, ef_mode="dense", state_dtype=jnp.float32,
                    algo="fedavg", double_ef=False, with_part=False,
-                   privacy=None, blocked=False, gate_ef=False):
+                   privacy=None, blocked=False, gate_ef=False,
+                   elementwise=False):
     """``blocked`` allocates a chunked pass's state as the engine does,
     (m, chunk, ...) rows; ``gate_ef`` adds the fault mode's EF gate and
-    empty-round guard (needs ``with_part``)."""
+    empty-round guard (needs ``with_part``); ``elementwise`` takes
+    `_elementwise_loss`."""
     params, loss_fn, make_batches, _ = _problem()
+    if elementwise:
+        loss_fn = _elementwise_loss
     batches = jax.tree.map(jnp.asarray, make_batches(0, N))
     # chunk >= N degenerates to the unchunked pass (N state rows)
     eff = chunk if chunk is not None and chunk < N else None
@@ -154,12 +171,26 @@ _PARITY_CASES = (
        pytest.param("topk", dict(blocked=True, algo="scaffold"),
                     id="blocked-topk-scaffold"),
        pytest.param("topk", dict(blocked=True, with_part=True, gate_ef=True),
-                    id="blocked-topk-gate_ef")])
+                    id="blocked-topk-gate_ef")]
+    # blocks of 2 (m = 5): the stacked block partials outnumber the rows
+    # of a block, so the fold after the scan carries most of the tree
+    + [pytest.param(name, dict(blocked=True, chunk=2, **o),
+                    id=f"blocked-{name}{s}-chunk2")
+       for name, o, s in (("none", {}, ""), ("topk", {}, ""),
+                          ("topk", dict(algo="scaffold"), "-scaffold"))]
+    # blocks of 1 (m = 10), the Mellum cell's shape: no in-block fold, the
+    # stacked partials carry the whole tree
+    + [pytest.param(name, dict(blocked=True, chunk=1, elementwise=True, **o),
+                    id=f"blocked-{name}{s}-chunk1")
+       for name, o, s in (("none", {}, ""), ("topk", {}, ""),
+                          ("topk", dict(algo="scaffold"), "-scaffold"))])
 
 
 @pytest.mark.parametrize("name,opts", _PARITY_CASES)
 def test_chunked_round_bitwise_parity(name, opts):
-    _assert_rounds_equal(_round_outputs(name, CHUNK, **opts),
+    opts = dict(opts)
+    chunk = opts.pop("chunk", CHUNK)
+    _assert_rounds_equal(_round_outputs(name, chunk, **opts),
                          _round_outputs(name, None, **opts))
 
 

@@ -7,6 +7,7 @@ not fit the chip's HBM. This is the only test file that describes the
 chip; the description is made inside a fixture, never at import time, so
 every pytest-xdist worker collects the same tests.
 """
+import functools
 import os
 import re
 
@@ -20,6 +21,7 @@ from benchmarks.common import make_linear_problem
 from repro.core import compat
 from repro.data import make_linear_datagen
 from repro.fl import runtime as rt
+from repro.fl import server
 from repro.kernels import ops, qsgd_rows, sign_ef_rows, topk_rows
 
 V5E_HBM_BYTES = 16 * 2**30
@@ -134,35 +136,106 @@ def test_fleet_engine_step_fits_v5e(one_chip, monkeypatch):
     assert total < V5E_HBM_BYTES, total
 
 
-def test_chunked_ef_state_is_written_in_place_per_block(one_chip,
-                                                          monkeypatch):
+@pytest.fixture(scope="module")
+def chunked_engine(one_chip):
+    """(n, chunk, d, compiled HLO text) of one flat-engine round of a
+    chunked top-k pass with dense EF, compiled for the described chip."""
+    n, chunk, d = 8, 2, (1 << 20) + 3 * 128
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "resolve_mode", lambda mode: "pallas")
+        jax.clear_caches()
+        params, loss_fn, _, w_star = make_linear_problem(d=d)
+        cfg = rt.SimConfig(n_devices=n, n_scheduled=4, rounds=1,
+                           chunk_size=chunk,
+                           datagen=make_linear_datagen(w_star),
+                           compression="topk")
+        wcfg = rt.wireless.WirelessConfig(n_devices=cfg.n_devices)
+        engine = rt._make_sim_fns(cfg, wcfg, loss_fn, False).engine
+        args = (jax.random.PRNGKey(cfg.seed),
+                rt.wireless.channel_params(wcfg),
+                rt._resolve_cparams(cfg, params), rt._resolve_aparams(cfg),
+                params)
+        arg_shapes = jax.tree.map(
+            lambda a: _sds(a.shape, a.dtype, one_chip), args)
+        text = jax.jit(engine).lower(*arg_shapes, None,
+                                     None).compile().as_text()
+    return n, chunk, d, text
+
+
+def _writes(text, scope):
+    """(shape, line) of each dynamic-update-slice whose innermost ``fl.*``
+    scope is ``scope``."""
+    out = []
+    for ln in text.splitlines():
+        inner = re.search(r'op_name="[^"]*/(fl\.\w+)/', ln)
+        if " dynamic-update-slice(" in ln and inner and inner[1] == scope:
+            shape = re.search(r" = f32\[([\d,]+)\]", ln)[1]
+            out.append((tuple(int(x) for x in shape.split(",")), ln))
+    return out
+
+
+def _aligned(ln):
+    return re.search(r'"is_index_aligned":\[(\w+)', ln)[1] == "true"
+
+
+def test_chunked_ef_state_is_written_in_place_per_block(chunked_engine):
     """The chunked client pass carries per-client EF rows as (blocks,
     chunk, D): each block writes only its own slab, at an aligned leading
     index. A flat (N, D) state on the TPU's (8, 128) tiles would put rows
     of every block in each tile, so each block's write would rewrite the
     whole state."""
-    monkeypatch.setattr(ops, "resolve_mode", lambda mode: "pallas")
-    jax.clear_caches()
-    n, chunk, d = 8, 2, (1 << 20) + 3 * 128
-    params, loss_fn, _, w_star = make_linear_problem(d=d)
-    cfg = rt.SimConfig(n_devices=n, n_scheduled=4, rounds=1,
-                       chunk_size=chunk, datagen=make_linear_datagen(w_star),
-                       compression="topk")
-    wcfg = rt.wireless.WirelessConfig(n_devices=cfg.n_devices)
-    engine = rt._make_sim_fns(cfg, wcfg, loss_fn, False).engine
-    args = (jax.random.PRNGKey(cfg.seed), rt.wireless.channel_params(wcfg),
-            rt._resolve_cparams(cfg, params), rt._resolve_aparams(cfg),
-            params)
-    arg_shapes = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
-                              args)
-    text = jax.jit(engine).lower(*arg_shapes, None, None).compile().as_text()
-    writes = [ln for ln in text.splitlines()
-              if " dynamic-update-slice(" in ln and "fl.client_state" in ln]
-    written = [tuple(int(x) for x in re.search(
-        r" = f32\[([\d,]+)\]", ln).group(1).split(",")) for ln in writes]
-    assert (n // chunk, chunk, d) in written, written
-    for shape, ln in zip(written, writes):
+    n, chunk, d, text = chunked_engine
+    writes = _writes(text, "fl.client_state")
+    assert (n // chunk, chunk, d) in [s for s, _ in writes], writes
+    for shape, ln in writes:
         assert not (len(shape) == 2 and shape[0] > chunk), ln
         if shape == (n // chunk, chunk, d):
-            aligned = re.search(r'"is_index_aligned":\[(\w+)', ln).group(1)
-            assert aligned == "true", ln
+            assert _aligned(ln), ln
+
+
+def test_chunked_block_partials_are_stacked_in_place(chunked_engine):
+    """The chunked client pass stacks each block's D-wide partial sum as
+    a (1, D) row of an (m, 1, D) stack: each block writes only its own
+    slab, at an aligned leading index. An (m, D) stack on the TPU's tiles
+    would put a row of every block in each tile, so each block's write
+    would rewrite the whole stack."""
+    n, chunk, d, text = chunked_engine
+    m = n // chunk
+    writes = _writes(text, "fl.aggregate")
+    assert (m, 1, d) in [s for s, _ in writes], writes
+    for shape, ln in writes:
+        assert not (len(shape) == 2 and shape[0] > 1), ln
+        if shape == (m, 1, d):
+            assert _aligned(ln), ln
+
+
+def test_chunked_blocks_of_one_relay_message_leaves_by_copy(one_chip):
+    """At blocks of one client the block's partial sum is its message, so
+    XLA may fold the (m, 1, D) stack's unit axis back into the message's
+    concatenate; the TPU compiler then relays each 2-D leaf by a reshape,
+    unrolled in the program (0.18 GiB of it at the Mellum client's
+    width), where a copy does. The chunked pass ends the concatenate at a
+    barrier."""
+    n = 4
+    params = {"w1": jnp.zeros((256, 512)), "w2": jnp.zeros((512, 128))}
+
+    def loss_fn(p, b):
+        h = jnp.tanh(b["x"] @ p["w1"]) @ p["w2"]
+        return jnp.mean((h - b["y"]) ** 2), {}
+
+    batches = {"x": jnp.zeros((n, 2, 8, 256)), "y": jnp.zeros((n, 2, 8, 128))}
+    state = server.init_fl_state(params, n, n_rows=(n, 1))
+    fn = jax.jit(functools.partial(
+        server.fl_round, loss_fn=loss_fn, algo="fedavg",
+        aparams=rt.algo_params(lr=0.1), chunk_size=1, n_clients=n,
+        participation=jnp.array([1.0, 0.0, 1.0, 0.0])))
+    shapes = jax.tree.map(
+        lambda a: _sds(jnp.shape(a), jnp.result_type(a), one_chip),
+        (state, batches))
+    text = fn.lower(*shapes).compile().as_text()
+    d = 256 * 512 + 512 * 128
+    writes = _writes(text, "fl.aggregate")
+    assert (n, 1, d) in [s for s, _ in writes], writes
+    relays = [ln for ln in text.splitlines()
+              if " reshape(" in ln and "fl.compress" in ln]
+    assert not relays, relays
